@@ -105,11 +105,6 @@ def main() -> None:
                          "groups {'w': 1}); use 8/16 for FM/DNN embeddings")
     ap.add_argument("--reps", type=int, default=11)
     ap.add_argument("--hot-batches", type=int, default=10)
-    ap.add_argument("--pallas-rows", type=int, default=4096,
-                    help="table size for the Pallas-interpret leg "
-                         "(interpret mode executes grid steps in Python; "
-                         "full 1M-row scale is a TPU measurement)")
-    ap.add_argument("--pallas-batch", type=int, default=256)
     ap.add_argument("--sweep-slots", type=int, nargs="*", default=None,
                     help="map capacities for the HBM/windowed-DMA sweep "
                          "(default 1M..16M; --quick defaults 1M,4M)")
@@ -189,28 +184,14 @@ def main() -> None:
         "apply_batch_rows_per_sec": args.batch / v_push,
         "speedup": s_push / v_push}
 
-    # -- Pallas-interpret gather through the PS layer ----------------------
-    pt = SparseTable(args.dim, init_capacity=args.pallas_rows,
-                     backend="pallas")
-    pt.ensure(ids[:args.pallas_rows])
-    p_hot = [rng.choice(ids[:args.pallas_rows],
-                        size=args.pallas_batch).astype(np.int64)
-             for _ in range(2)]
-    p_s = best_of(lambda b: pt.gather(b, create=True), p_hot, 2)
-    results["pallas_interpret"] = {
-        "rows": args.pallas_rows, "batch": args.pallas_batch,
-        "ensure_gather_rows_per_sec": args.pallas_batch / p_s,
-        "us_per_batch": p_s * 1e6,
-        "note": "interpret mode runs grid steps in Python; on TPU the same "
-                "call compiles to a Mosaic scalar-prefetch DMA pipeline"}
 
     # -- map-size sweep: fused lookup + FTRL apply vs map capacity ---------
-    # The point: past VMEM_SLOT_BOUND (~2M slots) the probe's key table
+    # The point: past VMEM_SLOT_BOUND (1M slots) the probe's key table
     # cannot stream into VMEM — the windowed-DMA HBM kernel takes over
     # (placement flips to "hbm") and the fused paths keep running, with
     # bit-equality gates against the host-authoritative arrays at every
-    # size. Interpret mode on CPU; the Mosaic path is exercised by the
-    # `tpu`-marked smoke test on real hardware.
+    # size. Interpret mode on CPU; the Mosaic path is compiled by
+    # tests/test_tpu_compile.py and run on a chip by chip_smoke.py.
     from repro.kernels.hashmap_probe import VMEM_SLOT_BOUND
     from repro.optim.optimizers import FTRL
 

@@ -22,6 +22,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.weips_ctr import FM_FTRL
 from repro.core import ClusterConfig, WeiPSCluster
 from repro.core.monitor import auc
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--emit-on-feedback", action="store_true",
                     help="positives train the moment feedback arrives")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cluster = WeiPSCluster(FM_FTRL, ClusterConfig(
         num_master=4, num_slave=2, num_replicas=2, num_partitions=8,

@@ -13,9 +13,11 @@ no per-row Python anywhere. ``backend`` selects the row engine:
 
   * ``"numpy"``  — NumPy fancy indexing; the reference path, and the fast
     path on CPU-only hosts.
-  * ``"pallas"`` — batched gather through the ``embedding_lookup`` Pallas
-    kernel (interpret mode off-TPU, Mosaic on TPU); FTRL row updates fuse
-    through ``ftrl_row_update`` (see ``Optimizer.update_rows``).
+  * ``"pallas"`` — serve lookups and FTRL updates run on the device
+    against a mirror of the table (``_DeviceMirror``): the ``hashmap_probe``
+    kernel resolves ids, XLA gathers and scatters the rows, and FTRL row
+    updates fuse through ``ftrl_row_update`` (interpret mode on the CPU
+    backend, Mosaic on TPU).
 """
 
 from __future__ import annotations
@@ -31,6 +33,17 @@ from repro.optim import Optimizer
 from repro.optim.optimizers import FTRL
 
 PS_BACKENDS = ("numpy", "pallas")
+
+
+def _scatter(table, idx: np.ndarray, rows: np.ndarray):
+    """Donated in-place row upload ``table[idx] = rows``, the index count
+    padded to its bucket (padding indexes past the end and is dropped)."""
+    from repro.kernels import ops
+    n = ops.bucket(len(idx))
+    bound = table.size if rows.ndim == 1 else table.shape[0]
+    return ops.embedding_scatter(
+        table, ops.pad_rows(idx.astype(np.int32), n, bound),
+        ops.pad_rows(rows, n, 0))
 
 
 class _DeviceMirror:
@@ -52,12 +65,13 @@ class _DeviceMirror:
     then ``mark_synced`` — steady-state training batches upload nothing
     but ids and grads.
 
-    Placement: maps small enough for the whole-table VMEM probe upload
-    exact-capacity limb arrays; past ``VMEM_SLOT_BOUND`` (or when the
-    table pins ``device_placement``) the limbs are wrap-padded
-    (``hashmap_probe.wrap_pad_limbs``) and probed by the windowed-DMA
-    HBM kernel. Upload traffic is counted (``sync_metrics`` surfaces
-    it)."""
+    The key limbs are uploaded in the probe's row layout
+    (``hashmap_probe.wrap_pad_limbs``). Placement: maps up to
+    ``VMEM_SLOT_BOUND`` slots are probed with the key table in VMEM,
+    larger ones (or any, when the table pins ``device_placement``) with
+    it in HBM. Device calls take id counts padded to ``ops.bucket`` sizes,
+    so each table shape compiles a handful of programs, not one per
+    batch. Upload traffic is counted (``sync_metrics`` surfaces it)."""
 
     def __init__(self, table: "SparseTable"):
         self._t = table
@@ -67,7 +81,6 @@ class _DeviceMirror:
         self.arenas: dict = {}
         self._placement: Optional[str] = None   # resolved at key sync
         self._cap = 0                           # capacity at last key sync
-        self._pad = 0                           # wrap-pad rows (hbm only)
         self.syncs = 0
         self.key_full_uploads = 0
         self.key_incremental_uploads = 0
@@ -100,48 +113,35 @@ class _DeviceMirror:
         from repro.kernels import hashmap_probe as _hm
         from repro.kernels import ops
         cap = m.capacity
-        placement = self._resolve_placement(cap)
         slots = None
-        if (self.keys_lo is not None and cap == self._cap
-                and placement == self._placement):
+        if self.keys_lo is not None and cap == self._cap:
             slots = m.dirty_slots_since(self._map_version)
         if slots is None:
-            # full upload: first sync, realloc/rehash, clear, placement
-            # flip, or journal overflow
-            klo, khi = ops.int64_limbs(m.key_table)
-            self._pad = 0
-            if placement == "hbm":
-                klo, khi = _hm.wrap_pad_limbs(klo, khi, cap=cap)
-                self._pad = klo.shape[0] - cap
+            # full upload: first sync, realloc/rehash, clear, or journal
+            # overflow
+            klo, khi = _hm.wrap_pad_limbs(*ops.int64_limbs(m.key_table),
+                                          cap=cap)
             self.keys_lo = jnp.asarray(klo)
             self.keys_hi = jnp.asarray(khi)
             self.slot_of = jnp.asarray(m.val_table.astype(np.int32))
             self.key_full_uploads += 1
-            self.key_bytes_uploaded += (klo.nbytes + khi.nbytes
-                                        + m.capacity * 4)
+            self.key_bytes_uploaded += klo.nbytes + khi.nbytes + cap * 4
         else:
             if len(slots):
-                klo, khi = ops.int64_limbs(m.key_table[slots])
-                sl = jnp.asarray(slots.astype(np.int32))
-                self.keys_lo = self.keys_lo.at[sl].set(jnp.asarray(klo))
-                self.keys_hi = self.keys_hi.at[sl].set(jnp.asarray(khi))
-                self.slot_of = self.slot_of.at[sl].set(
-                    jnp.asarray(m.val_table[slots].astype(np.int32)))
-                self.key_bytes_uploaded += len(slots) * 12
-                if self._pad:
-                    # dirty slots inside the wrap-pad mirror region must
-                    # land in both places
-                    wrap = slots[slots < self._pad]
-                    if len(wrap):
-                        wlo, whi = ops.int64_limbs(m.key_table[wrap])
-                        wl = jnp.asarray((wrap + cap).astype(np.int32))
-                        self.keys_lo = self.keys_lo.at[wl].set(
-                            jnp.asarray(wlo))
-                        self.keys_hi = self.keys_hi.at[wl].set(
-                            jnp.asarray(whi))
-                        self.key_bytes_uploaded += len(wrap) * 8
+                # every position of the padded layout that mirrors a
+                # dirty slot
+                span = self.keys_lo.size
+                pos = (slots[:, None]
+                       + cap * np.arange(-(-span // cap))).ravel()
+                pos = pos[pos < span]
+                klo, khi = ops.int64_limbs(m.key_table[pos % cap])
+                self.keys_lo = _scatter(self.keys_lo, pos, klo)
+                self.keys_hi = _scatter(self.keys_hi, pos, khi)
+                self.slot_of = _scatter(self.slot_of, slots,
+                                        m.val_table[slots].astype(np.int32))
+                self.key_bytes_uploaded += len(pos) * 8 + len(slots) * 4
             self.key_incremental_uploads += 1
-        self._placement = placement
+        self._placement = self._resolve_placement(cap)
         self._cap = cap
         self._map_version = m.version
         m.trim_dirty_log(m.version)
@@ -149,7 +149,6 @@ class _DeviceMirror:
     def sync(self) -> None:
         import jax.numpy as jnp
 
-        from repro.kernels import ops
         t = self._t
         m = t._map
         self.syncs += 1
@@ -168,10 +167,8 @@ class _DeviceMirror:
                 self.arena_bytes_uploaded += sum(v.nbytes
                                                  for v in host.values())
             elif len(dirty):
-                sl = dirty.astype(np.int32)
-                self.arenas = {
-                    k: ops.embedding_scatter(a, sl, host[k][dirty])
-                    for k, a in self.arenas.items()}
+                self.arenas = {k: _scatter(a, dirty, host[k][dirty])
+                               for k, a in self.arenas.items()}
                 self.arena_bytes_uploaded += len(dirty) * row_bytes
         self._synced_mut = t._mut
 
@@ -203,7 +200,7 @@ class SparseTable:
         self.dtype = dtype
         self.backend = backend
         # device key-table placement for the pallas backend: "auto" routes
-        # by capacity (VMEM below ~2M slots, HBM/windowed-DMA above);
+        # by capacity (VMEM up to VMEM_SLOT_BOUND slots, HBM above);
         # "vmem"/"hbm" pin it (tests and benchmarks exercise the HBM path
         # at small capacities this way)
         self.device_placement = "auto"
@@ -243,7 +240,9 @@ class SparseTable:
 
     def _grow(self, need: int) -> None:
         cap = self._w.shape[0]
-        new_cap = max(need, cap * 2)
+        # powers of two (from a power-of-two start): tables that grow
+        # alike share arena shapes, and so their compiled device programs
+        new_cap = max(1 << (need - 1).bit_length(), cap * 2)
         def grow(a, fill=0):
             out = np.full((new_cap,) + a.shape[1:], fill, dtype=a.dtype)
             out[:cap] = a
@@ -321,10 +320,7 @@ class SparseTable:
 
     # -- slot-level row access (shared by gather/scatter/apply_batch) -----
     def _fetch(self, arena: np.ndarray, sl: np.ndarray) -> np.ndarray:
-        if self.backend == "pallas" and len(sl):
-            from repro.kernels import ops
-            out = ops.embedding_lookup(arena, sl.astype(np.int32))
-            return np.asarray(out, dtype=arena.dtype)
+        # the host arrays are authoritative on both backends.
         # take(mode="clip") with in-bounds-by-construction slots: ~an order
         # faster than arena[sl] (skips the bounds-checked gather path)
         if arena.shape[1] == 1:      # dim-1 rows (LR): element gather beats
@@ -455,18 +451,29 @@ class SparseTable:
         the found mask comes off the device probe (the serve cache counts
         misses from it instead of re-probing on host) and ``slot`` lets
         LRU stats update without a host lookup."""
+        n = len(ids)
+        rows, found, slot = self._lookup_padded(ids)
+        return rows[:n], found[:n], slot[:n]
+
+    def _lookup_padded(self, ids: np.ndarray):
+        """``ops.fused_lookup`` over ``ids`` padded to their bucket with the
+        EMPTY sentinel, which the probe never finds: device rows and host
+        found/slot, padding included."""
         from repro.kernels import ops
         mir = self._mirror()
         mir.sync()
-        ilo, ihi = ops.int64_limbs(np.asarray(ids, np.int64))
+        ids = np.asarray(ids, np.int64)
+        ilo, ihi = ops.int64_limbs(ops.pad_rows(ids, ops.bucket(len(ids)),
+                                                _NO_ID))
         rows, found, slot = ops.fused_lookup(
             mir.keys_lo, mir.keys_hi, mir.slot_of, mir.arenas["w"],
             ilo, ihi, shift=mir.shift, placement=mir.placement)
         return rows, np.asarray(found), np.asarray(slot)
 
     def _gather_device(self, ids: np.ndarray) -> np.ndarray:
-        rows, _found, _slot = self.lookup_device(ids)
-        return np.asarray(rows, dtype=self.dtype)
+        # slice on the host: a device slice compiles once per id count
+        rows, _found, _slot = self._lookup_padded(ids)
+        return np.asarray(rows, dtype=self.dtype)[:len(ids)]
 
     def mirror_metrics(self) -> Optional[dict]:
         """Device-mirror upload counters (None until a pallas path has
@@ -487,19 +494,23 @@ class SparseTable:
         from repro.kernels import ops
         mir = self._mirror()
         mir.sync()
-        ilo, ihi = ops.int64_limbs(ids)
+        n = len(ids)
+        # padding ids are the EMPTY sentinel: never found, never written
+        nb = ops.bucket(n)
+        ilo, ihi = ops.int64_limbs(ops.pad_rows(ids, nb, _NO_ID))
         z_a, n_a, w_a, z2, n2, w2, found = ops.fused_ftrl_apply(
             mir.keys_lo, mir.keys_hi, mir.slot_of,
             mir.arenas["z"], mir.arenas["n"], mir.arenas["w"],
-            ilo, ihi, np.asarray(grads, np.float32),
+            ilo, ihi, ops.pad_rows(np.asarray(grads, np.float32), nb, 0),
             shift=mir.shift, alpha=alpha, beta=beta, l1=l1, l2=l2,
             placement=mir.placement)
         mir.arenas["z"], mir.arenas["n"], mir.arenas["w"] = z_a, n_a, w_a
-        assert bool(np.asarray(found).all()), \
-            "fused_ftrl_update on ids absent from the map (run ensure first)"
-        w_np = np.asarray(w2).astype(self.dtype, copy=False)
-        self.write_rows(sl, w_np, {"z": np.asarray(z2),
-                                   "n": np.asarray(n2)}, step=step)
+        if not np.asarray(found)[:n].all():
+            raise RuntimeError("fused_ftrl_update on ids absent from the "
+                               "map (run ensure first)")
+        w_np = np.asarray(w2)[:n].astype(self.dtype, copy=False)
+        self.write_rows(sl, w_np, {"z": np.asarray(z2)[:n],
+                                   "n": np.asarray(n2)[:n]}, step=step)
         mir.mark_synced()
         return w_np
 

@@ -180,8 +180,9 @@ class Int8Transform(Transform):
         if self._device_path and len(w):
             from repro.kernels import ops
             v = self.serve_values(w, slots).astype(np.float32, copy=False)
-            q, scale = ops.quantize_rows(v)
-            return {"q": np.asarray(q), "scale": np.asarray(scale)}
+            n = len(v)
+            q, scale = ops.quantize_rows(ops.pad_rows(v, ops.bucket(n), 0))
+            return {"q": np.asarray(q)[:n], "scale": np.asarray(scale)[:n]}
         return self._assemble(w, slots, self._quantize_np)
 
     @staticmethod
@@ -189,7 +190,10 @@ class Int8Transform(Transform):
         q = payload["q"]
         if backend == "pallas" and q.size:
             from repro.kernels import ops
-            return np.asarray(ops.dequantize_rows(q, payload["scale"]))
+            n = ops.bucket(len(q))
+            return np.asarray(ops.dequantize_rows(
+                ops.pad_rows(q, n, 0),
+                ops.pad_rows(payload["scale"], n, 1)))[:len(q)]
         return q.astype(np.float32) * payload["scale"]
 
 

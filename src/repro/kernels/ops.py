@@ -1,8 +1,15 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Jit'd public wrappers for the Pallas kernels, and the fused PS chains
+built from them.
 
-On CPU (this container) kernels execute in interpret mode — the kernel body
-runs in Python for correctness validation; on TPU the same calls compile to
-Mosaic. ``interpret`` resolves automatically from the backend.
+On the CPU backend kernels execute in interpret mode — the kernel body
+runs in Python for correctness validation; on TPU the same calls compile
+to Mosaic. ``interpret`` resolves from the backend.
+
+Row gathers and scatters on the PS arenas are XLA ops, not kernels: XLA
+stores a narrow ``(V, D)`` f32 arena (D = 1 or 8) with the row index as
+the minor (lane) dimension, compactly, while a Pallas operand is laid out
+row-major with D padded to 128 lanes — a whole-table relayout copy 128/D
+times the arena's size before any row moves.
 """
 
 from __future__ import annotations
@@ -16,14 +23,13 @@ import numpy as np
 
 from repro.kernels import decode_attention as _da
 from repro.kernels import delta_codec as _dc
-from repro.kernels import embedding_lookup as _el
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ftrl_row_update as _ftrl
 from repro.kernels import hashmap_probe as _hm
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def int64_limbs(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -36,44 +42,49 @@ def int64_limbs(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return np.ascontiguousarray(v[:, 0]), np.ascontiguousarray(v[:, 1])
 
 
-@functools.partial(jax.jit, static_argnames=())
-def embedding_lookup(table, ids):
-    return _el.embedding_lookup(table, ids, interpret=_interpret())
+def bucket(n: int) -> int:
+    """Padded length of an ``n``-row device call: the next power of two, at
+    least 1024 (the probe's own batch granule). Every distinct length
+    compiles its own program, so callers pad to these few."""
+    return max(1024, 1 << (max(n, 1) - 1).bit_length())
 
 
-@jax.jit
-def embedding_scatter_add(table, ids, updates):
-    return _el.embedding_scatter_add(table, ids, updates,
-                                     interpret=_interpret())
+def pad_rows(a: np.ndarray, n: int, fill) -> np.ndarray:
+    """Host array ``a`` extended along axis 0 to ``n`` rows of ``fill``."""
+    out = np.full((n,) + a.shape[1:], fill, dtype=a.dtype)
+    out[:len(a)] = a
+    return out
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def embedding_scatter(table, ids, updates):
-    return _el.embedding_scatter(table, ids, updates,
-                                 interpret=_interpret())
+    """Row scatter-SET ``table[ids[i]] = updates[i]`` into a DONATED table,
+    in place — the device mirror's incremental upload. The table is viewed
+    as rows shaped like ``updates[i]`` (a ``(R, 128)`` key-limb table takes
+    flat slot positions). ``ids`` are unique; out-of-range ids are
+    dropped."""
+    rows = table.reshape((-1,) + updates.shape[1:])
+    return rows.at[ids].set(updates.astype(table.dtype),
+                            mode="drop").reshape(table.shape)
 
 
 def _probe(keys_lo, keys_hi, ids_lo, ids_hi, *, shift, placement):
-    """Placement-dispatched probe (traced): ``"vmem"`` streams the whole
-    key table into VMEM per call (cheapest for small maps), ``"hbm"``
-    keeps it in ANY/HBM and DMAs probe windows (no VMEM capacity bound),
-    ``"auto"`` picks by capacity against ``VMEM_SLOT_BOUND``. The key
-    arrays may be wrap-padded (HBM layout); the VMEM kernel slices the
-    pad back off."""
-    cap = 1 << (64 - int(shift))
+    """Placement-dispatched probe (traced): ``"vmem"`` puts the whole key
+    table in VMEM per call (cheapest for small maps), ``"hbm"`` keeps it
+    in HBM and copies probe windows (no VMEM capacity bound), ``"auto"``
+    picks by capacity against ``VMEM_SLOT_BOUND``."""
     if placement == "auto":
+        cap = 1 << (64 - int(shift))
         placement = "hbm" if cap > _hm.VMEM_SLOT_BOUND else "vmem"
-    if placement == "hbm":
-        return _hm.hashmap_probe_hbm(keys_lo, keys_hi, ids_lo, ids_hi,
-                                     shift=shift, interpret=_interpret())
-    assert placement == "vmem", f"unknown placement {placement!r}"
-    return _hm.hashmap_probe(keys_lo[:cap], keys_hi[:cap], ids_lo, ids_hi,
-                             shift=shift, interpret=_interpret())
+    return _hm.hashmap_probe(keys_lo, keys_hi, ids_lo, ids_hi, shift=shift,
+                             placement=placement, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("shift", "placement"))
 def hashmap_probe(keys_lo, keys_hi, ids_lo, ids_hi, *, shift,
                   placement="auto"):
+    """Jitted ``kernels.hashmap_probe.hashmap_probe`` with ``"auto"``
+    placement by capacity: ``(pos, found)`` for each id."""
     return _probe(keys_lo, keys_hi, ids_lo, ids_hi, shift=shift,
                   placement=placement)
 
@@ -92,7 +103,7 @@ def fused_lookup(keys_lo, keys_hi, slot_of, arena, ids_lo, ids_hi, *,
     pos, found = _probe(keys_lo, keys_hi, ids_lo, ids_hi, shift=shift,
                         placement=placement)
     slot = jnp.where(found, jnp.take(slot_of, pos, mode="clip"), 0)
-    rows = _el.embedding_lookup(arena, slot, interpret=_interpret())
+    rows = jnp.take(arena, slot, axis=0, mode="clip")
     return (jnp.where(found[:, None], rows, jnp.zeros((), rows.dtype)),
             found, slot)
 
@@ -110,25 +121,25 @@ def fused_ftrl_apply(keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
 
     ``ids`` must be UNIQUE and PRESENT in the map (``MasterShard`` runs
     ``ensure`` before engaging the fused path; ``found`` is returned so
-    the caller can assert that). The three arenas are donated — callers
-    rebind them from the outputs (the device mirror keeps them resident
-    across batches). Row outputs (z', n', w') are returned as well so the
+    the caller can assert that — rows of ids not found are not written).
+    The three arenas are donated and updated in place — callers rebind
+    them from the outputs (the device mirror keeps them resident across
+    batches). Row outputs (z', n', w') are returned as well so the
     host-authoritative arrays can be updated without re-downloading whole
     arenas."""
     pos, found = _probe(keys_lo, keys_hi, ids_lo, ids_hi, shift=shift,
                         placement=placement)
-    slot = jnp.where(found, jnp.take(slot_of, pos, mode="clip"), 0)
-    z = _el.embedding_lookup(z_arena, slot, interpret=_interpret())
-    n = _el.embedding_lookup(n_arena, slot, interpret=_interpret())
+    # not-found ids scatter out of range, where mode="drop" discards them
+    slot = jnp.where(found, jnp.take(slot_of, pos, mode="clip"),
+                     z_arena.shape[0])
+    z = jnp.take(z_arena, slot, axis=0, mode="clip")
+    n = jnp.take(n_arena, slot, axis=0, mode="clip")
     z2, n2, w2 = _ftrl.ftrl_row_update(z, n, grads, alpha=alpha, beta=beta,
                                        l1=l1, l2=l2,
                                        interpret=_interpret())
-    z_arena = _el.embedding_scatter(z_arena, slot, z2,
-                                    interpret=_interpret())
-    n_arena = _el.embedding_scatter(n_arena, slot, n2,
-                                    interpret=_interpret())
-    w_arena = _el.embedding_scatter(w_arena, slot, w2.astype(w_arena.dtype),
-                                    interpret=_interpret())
+    z_arena = z_arena.at[slot].set(z2, mode="drop")
+    n_arena = n_arena.at[slot].set(n2, mode="drop")
+    w_arena = w_arena.at[slot].set(w2.astype(w_arena.dtype), mode="drop")
     return z_arena, n_arena, w_arena, z2, n2, w2, found
 
 

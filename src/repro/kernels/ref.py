@@ -7,17 +7,6 @@ import jax
 import jax.numpy as jnp
 
 
-def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
-    """table (V, D); ids (N,) -> (N, D)."""
-    return table[ids]
-
-
-def embedding_scatter_add(table: jax.Array, ids: jax.Array,
-                          updates: jax.Array) -> jax.Array:
-    """table (V, D); ids (N,); updates (N, D) -> (V, D) with += rows."""
-    return table.at[ids].add(updates.astype(table.dtype))
-
-
 def embedding_scatter(table: jax.Array, ids: jax.Array,
                       updates: jax.Array) -> jax.Array:
     """table (V, D); ids (N,) UNIQUE; updates (N, D) -> (V, D) with rows

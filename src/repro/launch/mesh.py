@@ -15,12 +15,22 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     The ``pod`` axis joins batch/data sharding only (pure DP across pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    axes = ("data", "model")
+    return jax.make_mesh((data, model), axes, axis_types=_auto(axes))
+
+
+def _auto(axes: tuple) -> tuple:
+    """Auto (compiler-propagated) axis types. ``jax.make_mesh`` defaults to
+    Explicit axes, under which the embed gather ``embed[tokens]`` of a
+    ``P(model, data)`` table by ``data``-sharded tokens has no legal output
+    sharding (``DuplicateSpecError``); the models place activations with
+    ``with_sharding_constraint`` and leave the rest to propagation."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 # TPU v5e hardware constants (per chip) for the roofline model.

@@ -253,6 +253,9 @@ class ClusterRuntime:
             os.path.abspath(__file__))))
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        # workers hold numpy shards; keep them off an accelerator the
+        # supervisor (or another process) may hold
+        env["JAX_PLATFORMS"] = "cpu"
         log = open(spec.log_path, "ab", buffering=0)
         self.procs[spec.name] = subprocess.Popen(
             spec.argv, stdout=log, stderr=subprocess.STDOUT, env=env)

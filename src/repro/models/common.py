@@ -9,25 +9,11 @@ import jax.numpy as jnp
 
 
 def mesh_axis_names() -> tuple:
-    """Axis names of the mesh currently in scope, () when mesh-less.
+    """Axis names of the mesh set by ``jax.set_mesh``, () when mesh-less.
 
     Sharding-constraint helpers key off this to stay inert in mesh-less
-    unit tests. Reads the new-style abstract mesh where the running jax
-    exposes it (jax >= 0.5: ``jax.sharding.get_abstract_mesh``) and falls
-    back to the classic ``with Mesh(...):`` thread resources otherwise —
-    on jax 0.4.x the public accessor does not exist and the abstract mesh
-    is unset under a classic mesh context, so both reads are needed.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        names = get().axis_names
-        if names:
-            return names
-    try:
-        from jax._src import mesh as mesh_lib
-        return mesh_lib.thread_resources.env.physical_mesh.axis_names
-    except Exception:                       # pragma: no cover - jax drift
-        return ()
+    unit tests."""
+    return jax.sharding.get_abstract_mesh().axis_names
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

@@ -21,23 +21,16 @@ def pytest_addoption(parser):
 
 
 def pytest_collection_modifyitems(config, items):
-    """Auto-skip: ``tpu``-marked tests (non-interpret Pallas) off-TPU, so
-    the suite is green on CPU CI runners; ``slow``/``chaos`` unless opted
-    in (chaos tests spawn a process per PS shard — minutes, not ms)."""
-    import jax
-    on_tpu = jax.default_backend() == "tpu"
+    """Auto-skip ``slow``/``chaos`` tests unless opted in (chaos tests
+    spawn a process per PS shard — minutes, not ms)."""
     run_slow = config.getoption("--runslow") or bool(os.environ.get("RUN_SLOW"))
     run_chaos = config.getoption("--chaos") or bool(os.environ.get("RUN_CHAOS"))
-    skip_tpu = pytest.mark.skip(
-        reason="requires a real TPU (non-interpret Pallas)")
     skip_slow = pytest.mark.skip(reason="slow: pass --runslow or RUN_SLOW=1")
     skip_chaos = pytest.mark.skip(reason="chaos: pass --chaos or RUN_CHAOS=1")
     # match the actual @pytest.mark markers, not item.keywords — keywords
     # include every parent node's *name*, so the tests/chaos directory
     # itself would gate even unmarked (in-process, tier-1) tests in it
     for item in items:
-        if item.get_closest_marker("tpu") and not on_tpu:
-            item.add_marker(skip_tpu)
         if item.get_closest_marker("slow") and not run_slow:
             item.add_marker(skip_slow)
         if item.get_closest_marker("chaos") and not run_chaos:

@@ -1,6 +1,6 @@
 """PS backend equivalence: the ``pallas`` row engine (interpret mode on
 CPU, Mosaic on TPU) must match the ``numpy`` reference path through the
-real PS layer — SlaveShard serve lookups via the ``embedding_lookup``
+real PS layer — SlaveShard serve lookups via the ``hashmap_probe``
 kernel and MasterShard FTRL pushes via the fused ``ftrl_row_update``
 kernel. This is the acceptance gate that the shipped kernels are actually
 exercised by the parameter server, not just by kernel unit tests."""
