@@ -14,6 +14,11 @@ Master 0 is pre-seeded with ids the stream never draws, enough that its
 hash maps outgrow the VMEM probe bound: its tables are probed with the
 key table in HBM, the others with it in VMEM.
 
+Before the cluster runs, the compiled probe alone is held bit-equal to
+the host hash map on present, deleted and absent ids, at capacities from
+below one probe window to past the VMEM bound, in every placement the
+table fits.
+
 Run: ``python chip_smoke.py`` on a machine with a TPU. It refuses any
 other platform. The last line of its output is a JSON object naming the
 device; the lines before it are the evidence.
@@ -30,6 +35,41 @@ import numpy as np
 
 PRED_ATOL = 1e-4            # predictions, pallas vs numpy
 ROW_RTOL, ROW_ATOL = 1e-5, 1e-6   # master z, n, w rows
+
+
+def probe_check(caps, *, seed: int = 1, log=print) -> None:
+    """``ops.hashmap_probe`` against ``IdHashMap._probe`` on maps of each
+    capacity in ``caps``, with the key table laid out as the device
+    mirror uploads it: ``vmem`` placement where the table fits under
+    ``VMEM_SLOT_BOUND``, ``hbm`` always. Raises AssertionError on any
+    difference."""
+    from repro.core.hashmap import IdHashMap
+    from repro.kernels import hashmap_probe as hm
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(seed)
+    for cap in caps:
+        m = IdHashMap(cap)
+        ids = rng.choice(1 << 40, size=max(2, min(4096, cap // 8)),
+                         replace=False).astype(np.int64)
+        m.put(ids, np.arange(len(ids)))
+        m.delete(ids[::5])
+        assert m.capacity == cap, (m.capacity, cap)
+        qs = np.concatenate([ids, ids + (1 << 40)])     # + absent ids
+        host_pos, host_found = m._probe(qs)
+        klo, khi = hm.wrap_pad_limbs(*ops.int64_limbs(m.key_table), cap=cap)
+        qlo, qhi = ops.int64_limbs(qs)
+        for placement in ("vmem", "hbm"):
+            if placement == "vmem" and cap > hm.VMEM_SLOT_BOUND:
+                continue
+            pos, found = ops.hashmap_probe(klo, khi, qlo, qhi,
+                                           shift=int(m.shift),
+                                           placement=placement)
+            pos, found = np.asarray(pos), np.asarray(found)
+            np.testing.assert_array_equal(found, host_found)
+            np.testing.assert_array_equal(pos[found], host_pos[host_found])
+            log(f"probe cap={cap} placement={placement}: "
+                f"{int(found.sum())} found of {len(qs)}, equal to host map")
 
 
 def run(backend: str, *, ticks: int, events: int, requests: int,
@@ -98,10 +138,17 @@ def run(backend: str, *, ticks: int, events: int, requests: int,
 
 
 def smoke(*, ticks: int = 30, events: int = 4096, requests: int = 8,
-          request_examples: int = 2048, seed: int = 0, log=print) -> dict:
-    """Run the pallas backend and the numpy reference, check that the
-    device path ran and that both agree; raise AssertionError where not.
-    Returns the largest deviations."""
+          request_examples: int = 2048,
+          probe_caps=(1 << 4, 1 << 12, 1 << 20, 1 << 22), seed: int = 0,
+          log=print) -> dict:
+    """Check the probe alone (``probe_check``; the default capacities
+    bracket the v5e VMEM bound of 2^20 slots), then run the pallas
+    backend and the numpy reference, check that the device path ran and
+    that both agree; raise AssertionError where not. Returns the largest
+    deviations."""
+    t0 = time.perf_counter()
+    probe_check(probe_caps, log=log)
+    log(f"probe check s: {time.perf_counter() - t0:.3f}")
     kw = dict(ticks=ticks, events=events, requests=requests,
               request_examples=request_examples, seed=seed, log=log)
     dev = run("pallas", **kw)

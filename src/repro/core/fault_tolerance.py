@@ -89,9 +89,8 @@ def _pack_rows(a: np.ndarray, backend: str) -> dict:
     from repro.core.transform import Int8Transform
     if backend == "pallas" and a.size:
         from repro.kernels import ops
-        q, s = ops.quantize_rows(
-            np.ascontiguousarray(a, dtype=np.float32))
-        return {"q": np.asarray(q), "scale": np.asarray(s)}
+        q, s = ops.quantize_rows(a)
+        return {"q": q, "scale": s}
     return Int8Transform._quantize_np(a)
 
 

@@ -35,17 +35,6 @@ from repro.optim.optimizers import FTRL
 PS_BACKENDS = ("numpy", "pallas")
 
 
-def _scatter(table, idx: np.ndarray, rows: np.ndarray):
-    """Donated in-place row upload ``table[idx] = rows``, the index count
-    padded to its bucket (padding indexes past the end and is dropped)."""
-    from repro.kernels import ops
-    n = ops.bucket(len(idx))
-    bound = table.size if rows.ndim == 1 else table.shape[0]
-    return ops.embedding_scatter(
-        table, ops.pad_rows(idx.astype(np.int32), n, bound),
-        ops.pad_rows(rows, n, 0))
-
-
 class _DeviceMirror:
     """Lazily-synced device copy of a ``SparseTable``'s probe state (key
     limbs + slot map) and arenas — what lets the ``pallas`` backend run
@@ -69,9 +58,7 @@ class _DeviceMirror:
     (``hashmap_probe.wrap_pad_limbs``). Placement: maps up to
     ``VMEM_SLOT_BOUND`` slots are probed with the key table in VMEM,
     larger ones (or any, when the table pins ``device_placement``) with
-    it in HBM. Device calls take id counts padded to ``ops.bucket`` sizes,
-    so each table shape compiles a handful of programs, not one per
-    batch. Upload traffic is counted (``sync_metrics`` surfaces it)."""
+    it in HBM. Upload traffic is counted (``sync_metrics`` surfaces it)."""
 
     def __init__(self, table: "SparseTable"):
         self._t = table
@@ -135,10 +122,10 @@ class _DeviceMirror:
                        + cap * np.arange(-(-span // cap))).ravel()
                 pos = pos[pos < span]
                 klo, khi = ops.int64_limbs(m.key_table[pos % cap])
-                self.keys_lo = _scatter(self.keys_lo, pos, klo)
-                self.keys_hi = _scatter(self.keys_hi, pos, khi)
-                self.slot_of = _scatter(self.slot_of, slots,
-                                        m.val_table[slots].astype(np.int32))
+                self.keys_lo = ops.embedding_scatter(self.keys_lo, pos, klo)
+                self.keys_hi = ops.embedding_scatter(self.keys_hi, pos, khi)
+                self.slot_of = ops.embedding_scatter(
+                    self.slot_of, slots, m.val_table[slots].astype(np.int32))
                 self.key_bytes_uploaded += len(pos) * 8 + len(slots) * 4
             self.key_incremental_uploads += 1
         self._placement = self._resolve_placement(cap)
@@ -149,6 +136,7 @@ class _DeviceMirror:
     def sync(self) -> None:
         import jax.numpy as jnp
 
+        from repro.kernels import ops
         t = self._t
         m = t._map
         self.syncs += 1
@@ -167,7 +155,8 @@ class _DeviceMirror:
                 self.arena_bytes_uploaded += sum(v.nbytes
                                                  for v in host.values())
             elif len(dirty):
-                self.arenas = {k: _scatter(a, dirty, host[k][dirty])
+                self.arenas = {k: ops.embedding_scatter(a, dirty,
+                                                        host[k][dirty])
                                for k, a in self.arenas.items()}
                 self.arena_bytes_uploaded += len(dirty) * row_bytes
         self._synced_mut = t._mut
@@ -451,29 +440,21 @@ class SparseTable:
         the found mask comes off the device probe (the serve cache counts
         misses from it instead of re-probing on host) and ``slot`` lets
         LRU stats update without a host lookup."""
-        n = len(ids)
-        rows, found, slot = self._lookup_padded(ids)
-        return rows[:n], found[:n], slot[:n]
-
-    def _lookup_padded(self, ids: np.ndarray):
-        """``ops.fused_lookup`` over ``ids`` padded to their bucket with the
-        EMPTY sentinel, which the probe never finds: device rows and host
-        found/slot, padding included."""
         from repro.kernels import ops
         mir = self._mirror()
         mir.sync()
-        ids = np.asarray(ids, np.int64)
-        ilo, ihi = ops.int64_limbs(ops.pad_rows(ids, ops.bucket(len(ids)),
-                                                _NO_ID))
-        rows, found, slot = ops.fused_lookup(
-            mir.keys_lo, mir.keys_hi, mir.slot_of, mir.arenas["w"],
-            ilo, ihi, shift=mir.shift, placement=mir.placement)
-        return rows, np.asarray(found), np.asarray(slot)
+        return ops.fused_lookup(mir.keys_lo, mir.keys_hi, mir.slot_of,
+                                mir.arenas["w"], ids, shift=mir.shift,
+                                placement=mir.placement)
 
     def _gather_device(self, ids: np.ndarray) -> np.ndarray:
-        # slice on the host: a device slice compiles once per id count
-        rows, _found, _slot = self._lookup_padded(ids)
-        return np.asarray(rows, dtype=self.dtype)[:len(ids)]
+        from repro.kernels import ops
+        mir = self._mirror()
+        mir.sync()
+        rows = ops.fused_gather(mir.keys_lo, mir.keys_hi, mir.slot_of,
+                                mir.arenas["w"], ids, shift=mir.shift,
+                                placement=mir.placement)
+        return rows.astype(self.dtype, copy=False)
 
     def mirror_metrics(self) -> Optional[dict]:
         """Device-mirror upload counters (None until a pallas path has
@@ -494,23 +475,17 @@ class SparseTable:
         from repro.kernels import ops
         mir = self._mirror()
         mir.sync()
-        n = len(ids)
-        # padding ids are the EMPTY sentinel: never found, never written
-        nb = ops.bucket(n)
-        ilo, ihi = ops.int64_limbs(ops.pad_rows(ids, nb, _NO_ID))
         z_a, n_a, w_a, z2, n2, w2, found = ops.fused_ftrl_apply(
             mir.keys_lo, mir.keys_hi, mir.slot_of,
-            mir.arenas["z"], mir.arenas["n"], mir.arenas["w"],
-            ilo, ihi, ops.pad_rows(np.asarray(grads, np.float32), nb, 0),
+            mir.arenas["z"], mir.arenas["n"], mir.arenas["w"], ids, grads,
             shift=mir.shift, alpha=alpha, beta=beta, l1=l1, l2=l2,
             placement=mir.placement)
         mir.arenas["z"], mir.arenas["n"], mir.arenas["w"] = z_a, n_a, w_a
-        if not np.asarray(found)[:n].all():
+        if not found.all():
             raise RuntimeError("fused_ftrl_update on ids absent from the "
                                "map (run ensure first)")
-        w_np = np.asarray(w2)[:n].astype(self.dtype, copy=False)
-        self.write_rows(sl, w_np, {"z": np.asarray(z2)[:n],
-                                   "n": np.asarray(n2)[:n]}, step=step)
+        w_np = w2.astype(self.dtype, copy=False)
+        self.write_rows(sl, w_np, {"z": z2, "n": n2}, step=step)
         mir.mark_synced()
         return w_np
 

@@ -179,10 +179,8 @@ class Int8Transform(Transform):
         # pusher passes a (n, 0) w placeholder (columns come from slots)
         if self._device_path and len(w):
             from repro.kernels import ops
-            v = self.serve_values(w, slots).astype(np.float32, copy=False)
-            n = len(v)
-            q, scale = ops.quantize_rows(ops.pad_rows(v, ops.bucket(n), 0))
-            return {"q": np.asarray(q)[:n], "scale": np.asarray(scale)[:n]}
+            q, scale = ops.quantize_rows(self.serve_values(w, slots))
+            return {"q": q, "scale": scale}
         return self._assemble(w, slots, self._quantize_np)
 
     @staticmethod
@@ -190,10 +188,7 @@ class Int8Transform(Transform):
         q = payload["q"]
         if backend == "pallas" and q.size:
             from repro.kernels import ops
-            n = ops.bucket(len(q))
-            return np.asarray(ops.dequantize_rows(
-                ops.pad_rows(q, n, 0),
-                ops.pad_rows(payload["scale"], n, 1)))[:len(q)]
+            return ops.dequantize_rows(q, payload["scale"])
         return q.astype(np.float32) * payload["scale"]
 
 

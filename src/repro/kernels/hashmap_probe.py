@@ -81,10 +81,11 @@ _DMA_WINDOW = 256
 _LANES = 128                        # key slots per table row
 _TILE = 8                           # rows copied per id: one (8, 128) tile
 # Ids per SMEM block of window offsets: XLA tiles a 1-D int32 array by
-# 1024, and Mosaic takes the block only in that layout. Batches pad to it.
-_OFFSET_BLOCK = 1024
+# 1024, and Mosaic takes the block only in that layout. Batches pad to it
+# (``ops`` pads its calls to multiples of it, so nothing pads twice).
+OFFSET_BLOCK = 1024
 # Ids probed per grid step: the lane width of the (1, 1, chunk) state
-# blocks, and a divisor of _OFFSET_BLOCK.
+# blocks, and a divisor of OFFSET_BLOCK.
 _DMA_CHUNK = 128
 # Default scoped-VMEM limit of a TPU v5e kernel (the smallest of the TPU
 # generations this code targets). The VMEM placement holds the whole
@@ -174,7 +175,7 @@ def _probe_kernel(first_s, start_now, start_next, klo, khi, qlo_ref, qhi_ref,
     that hold this and the next chunk's."""
     i = pl.program_id(0)
     nsteps = pl.num_programs(0)
-    per_block = _OFFSET_BLOCK // chunk
+    per_block = OFFSET_BLOCK // chunk
     now0 = (i % per_block) * chunk
     next0 = ((i + 1) % per_block) * chunk
     nrows = _fetch_rows(window)
@@ -280,8 +281,8 @@ def _probe_pass(klo, khi, qlo, qhi, pos, found, active, cur, first, *,
                 cap, window, chunk, placement, interpret):
     nsteps = cur.shape[0]
     npad = nsteps * chunk
-    per_block = _OFFSET_BLOCK // chunk
-    nblocks = npad // _OFFSET_BLOCK
+    per_block = OFFSET_BLOCK // chunk
+    nblocks = npad // OFFSET_BLOCK
     kernel = functools.partial(_probe_kernel, cap=cap, window=window,
                                chunk=chunk, placement=placement)
     col = pl.BlockSpec((1, 1, chunk), lambda i, first_s: (i, 0, 0))
@@ -292,10 +293,10 @@ def _probe_pass(klo, khi, qlo, qhi, pos, found, active, cur, first, *,
         num_scalar_prefetch=1,                          # round-1 flag
         grid=(nsteps,),
         in_specs=[
-            pl.BlockSpec((_OFFSET_BLOCK,),              # window offsets of
+            pl.BlockSpec((OFFSET_BLOCK,),              # window offsets of
                          lambda i, first_s: (i // per_block,),  # this and
                          memory_space=pltpu.SMEM),      # the next chunk
-            pl.BlockSpec((_OFFSET_BLOCK,),
+            pl.BlockSpec((OFFSET_BLOCK,),
                          lambda i, first_s: (jnp.minimum(
                              (i + 1) // per_block, nblocks - 1),),
                          memory_space=pltpu.SMEM),
@@ -343,7 +344,7 @@ def hashmap_probe(keys_lo: jax.Array, keys_hi: jax.Array,
     assert placement in ("vmem", "hbm"), f"unknown placement {placement!r}"
     cap = 1 << (64 - int(shift))
     w = min(window, cap)
-    assert _fetch_rows(w) <= _TILE and _OFFSET_BLOCK % chunk == 0
+    assert _fetch_rows(w) <= _TILE and OFFSET_BLOCK % chunk == 0
     n = ids_lo.shape[0]
     if n == 0:
         return (jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.bool_))
@@ -352,7 +353,7 @@ def hashmap_probe(keys_lo: jax.Array, keys_hi: jax.Array,
     assert keys_lo.shape == (_rows(cap), _LANES), \
         f"key table must be ({cap},) or wrap-padded ({_rows(cap)}, 128)"
 
-    npad = -(-n // _OFFSET_BLOCK) * _OFFSET_BLOCK
+    npad = -(-n // OFFSET_BLOCK) * OFFSET_BLOCK
     zpad = npad - n
     qlo = jnp.concatenate([ids_lo, jnp.zeros((zpad,), jnp.uint32)])
     qhi = jnp.concatenate([ids_hi, jnp.zeros((zpad,), jnp.uint32)])

@@ -10,6 +10,12 @@ stores a narrow ``(V, D)`` f32 arena (D = 1 or 8) with the row index as
 the minor (lane) dimension, compactly, while a Pallas operand is laid out
 row-major with D padded to 128 lanes — a whole-table relayout copy 128/D
 times the arena's size before any row moves.
+
+The PS entry points (``embedding_scatter``, ``fused_lookup``,
+``fused_gather``, ``fused_ftrl_apply``, ``quantize_rows``,
+``dequantize_rows``) take host arrays of any length, pad them to a few
+power-of-two lengths — every distinct length compiles its own program —
+and return unpadded results.
 """
 
 from __future__ import annotations
@@ -42,30 +48,46 @@ def int64_limbs(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return np.ascontiguousarray(v[:, 0]), np.ascontiguousarray(v[:, 1])
 
 
-def bucket(n: int) -> int:
+def _bucket(n: int) -> int:
     """Padded length of an ``n``-row device call: the next power of two, at
-    least 1024 (the probe's own batch granule). Every distinct length
-    compiles its own program, so callers pad to these few."""
-    return max(1024, 1 << (max(n, 1) - 1).bit_length())
+    least the probe's batch granule (``hashmap_probe.OFFSET_BLOCK``)."""
+    return max(_hm.OFFSET_BLOCK, 1 << (max(n, 1) - 1).bit_length())
 
 
-def pad_rows(a: np.ndarray, n: int, fill) -> np.ndarray:
-    """Host array ``a`` extended along axis 0 to ``n`` rows of ``fill``."""
+def _pad(a, n: int, fill) -> np.ndarray:
+    """Host copy of ``a`` extended along axis 0 to ``n`` rows of ``fill``."""
+    a = np.asarray(a)
     out = np.full((n,) + a.shape[1:], fill, dtype=a.dtype)
     out[:len(a)] = a
     return out
 
 
+def _id_limbs(ids, n: int):
+    """Host int64 ids as uint32 limbs, padded to ``n`` with the map's EMPTY
+    sentinel (``core.hashmap.EMPTY``), which the probe never finds."""
+    return int64_limbs(_pad(np.asarray(ids, np.int64), n,
+                            np.iinfo(np.int64).min))
+
+
 @functools.partial(jax.jit, donate_argnums=0)
-def embedding_scatter(table, ids, updates):
-    """Row scatter-SET ``table[ids[i]] = updates[i]`` into a DONATED table,
-    in place — the device mirror's incremental upload. The table is viewed
-    as rows shaped like ``updates[i]`` (a ``(R, 128)`` key-limb table takes
-    flat slot positions). ``ids`` are unique; out-of-range ids are
-    dropped."""
+def _scatter_program(table, ids, updates):
     rows = table.reshape((-1,) + updates.shape[1:])
     return rows.at[ids].set(updates.astype(table.dtype),
                             mode="drop").reshape(table.shape)
+
+
+def embedding_scatter(table, ids, updates):
+    """Row scatter-SET ``table[ids[i]] = updates[i]`` into a DONATED device
+    table, in place — the device mirror's incremental upload. The table is
+    viewed as rows shaped like ``updates[i]`` (a ``(R, 128)`` key-limb
+    table takes flat slot positions). ``ids`` (unique) and ``updates`` are
+    host arrays of any length; out-of-range ids are dropped."""
+    updates = np.asarray(updates)
+    nb = _bucket(len(updates))
+    # padding indexes one row past the end, where mode="drop" discards it
+    past_end = table.size // max(1, int(np.prod(updates.shape[1:])))
+    return _scatter_program(table, _pad(np.asarray(ids, np.int32), nb,
+                                        past_end), _pad(updates, nb, 0))
 
 
 def _probe(keys_lo, keys_hi, ids_lo, ids_hi, *, shift, placement):
@@ -90,16 +112,8 @@ def hashmap_probe(keys_lo, keys_hi, ids_lo, ids_hi, *, shift,
 
 
 @functools.partial(jax.jit, static_argnames=("shift", "placement"))
-def fused_lookup(keys_lo, keys_hi, slot_of, arena, ids_lo, ids_hi, *,
-                 shift, placement="auto"):
-    """Fused probe→gather: serve-path lookup against a device-resident
-    table mirror, one jit — no host hop between the probe and the row
-    gather. ``slot_of`` is the map's value table (key-slot → arena slot,
-    int32). Missing rows come back as zeros. Returns (rows (N, D), found
-    (N,) bool, slot (N,) int32 — arena slots, 0 where not found): the
-    found mask lets callers count cache misses straight off the device
-    probe, the slots let them update host-side LRU stats, neither costs
-    a host re-probe."""
+def _lookup_program(keys_lo, keys_hi, slot_of, arena, ids_lo, ids_hi, *,
+                    shift, placement="auto"):
     pos, found = _probe(keys_lo, keys_hi, ids_lo, ids_hi, shift=shift,
                         placement=placement)
     slot = jnp.where(found, jnp.take(slot_of, pos, mode="clip"), 0)
@@ -108,25 +122,41 @@ def fused_lookup(keys_lo, keys_hi, slot_of, arena, ids_lo, ids_hi, *,
             found, slot)
 
 
+def fused_lookup(keys_lo, keys_hi, slot_of, arena, ids, *, shift,
+                 placement="auto"):
+    """Fused probe→gather: serve-path lookup of host int64 ``ids`` (any
+    length) against a device-resident table mirror, one jit — no host hop
+    between the probe and the row gather. ``slot_of`` is the map's value
+    table (key-slot → arena slot, int32). Missing rows come back as zeros.
+    Returns (rows (N, D) device array, found (N,) bool, slot (N,) int32 —
+    arena slots, 0 where not found — both host arrays): the found mask
+    lets callers count cache misses straight off the device probe, the
+    slots let them update host-side LRU stats, neither costs a host
+    re-probe."""
+    n = len(ids)
+    rows, found, slot = _lookup_program(
+        keys_lo, keys_hi, slot_of, arena, *_id_limbs(ids, _bucket(n)),
+        shift=shift, placement=placement)
+    return rows[:n], np.asarray(found)[:n], np.asarray(slot)[:n]
+
+
+def fused_gather(keys_lo, keys_hi, slot_of, arena, ids, *, shift,
+                 placement="auto"):
+    """``fused_lookup``'s rows as a host array. Sliced on the host: a
+    device slice compiles a program for every id count."""
+    rows, _, _ = _lookup_program(
+        keys_lo, keys_hi, slot_of, arena, *_id_limbs(ids, _bucket(len(ids))),
+        shift=shift, placement=placement)
+    return np.asarray(rows)[:len(ids)]
+
+
 @functools.partial(jax.jit,
                    static_argnames=("shift", "alpha", "beta", "l1", "l2",
                                     "placement"),
                    donate_argnums=(3, 4, 5))
-def fused_ftrl_apply(keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
-                     ids_lo, ids_hi, grads, *, shift, alpha, beta, l1, l2,
-                     placement="auto"):
-    """The fused sparse training hot path, one jit end to end:
-    probe → gather (z, n) → FTRL row update → scatter (z', n', w') back
-    into the arenas. No stage output ever leaves the device.
-
-    ``ids`` must be UNIQUE and PRESENT in the map (``MasterShard`` runs
-    ``ensure`` before engaging the fused path; ``found`` is returned so
-    the caller can assert that — rows of ids not found are not written).
-    The three arenas are donated and updated in place — callers rebind
-    them from the outputs (the device mirror keeps them resident across
-    batches). Row outputs (z', n', w') are returned as well so the
-    host-authoritative arrays can be updated without re-downloading whole
-    arenas."""
+def _ftrl_program(keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
+                  ids_lo, ids_hi, grads, *, shift, alpha, beta, l1, l2,
+                  placement="auto"):
     pos, found = _probe(keys_lo, keys_hi, ids_lo, ids_hi, shift=shift,
                         placement=placement)
     # not-found ids scatter out of range, where mode="drop" discards them
@@ -143,6 +173,33 @@ def fused_ftrl_apply(keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
     return z_arena, n_arena, w_arena, z2, n2, w2, found
 
 
+def fused_ftrl_apply(keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
+                     ids, grads, *, shift, alpha, beta, l1, l2,
+                     placement="auto"):
+    """The fused sparse training hot path, one jit end to end:
+    probe → gather (z, n) → FTRL row update → scatter (z', n', w') back
+    into the arenas. No stage output ever leaves the device.
+
+    ``ids`` (host int64, any length) must be UNIQUE and PRESENT in the
+    map (``MasterShard`` runs ``ensure`` before engaging the fused path;
+    ``found`` is returned so the caller can assert that — rows of ids not
+    found are not written); ``grads`` are their (N, D) host rows. The
+    three arenas are donated and updated in place — callers rebind them
+    from the outputs (the device mirror keeps them resident across
+    batches). The row outputs (z', n', w') and ``found`` come back as host
+    arrays, so the host-authoritative arrays can be updated without
+    re-downloading whole arenas."""
+    n = len(ids)
+    nb = _bucket(n)
+    z_a, n_a, w_a, z2, n2, w2, found = _ftrl_program(
+        keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
+        *_id_limbs(ids, nb), _pad(np.asarray(grads, np.float32), nb, 0),
+        shift=shift, alpha=alpha, beta=beta, l1=l1, l2=l2,
+        placement=placement)
+    return (z_a, n_a, w_a, np.asarray(z2)[:n], np.asarray(n2)[:n],
+            np.asarray(w2)[:n], np.asarray(found)[:n])
+
+
 @functools.partial(jax.jit, static_argnames=("alpha", "beta", "l1", "l2"))
 def ftrl_row_update(z, n, g, *, alpha=0.05, beta=1.0, l1=1.0, l2=1.0):
     return _ftrl.ftrl_row_update(z, n, g, alpha=alpha, beta=beta, l1=l1,
@@ -150,13 +207,29 @@ def ftrl_row_update(z, n, g, *, alpha=0.05, beta=1.0, l1=1.0, l2=1.0):
 
 
 @jax.jit
-def quantize_rows(x):
+def _quantize_program(x):
     return _dc.quantize_rows(x, interpret=_interpret())
 
 
 @jax.jit
-def dequantize_rows(q, scale):
+def _dequantize_program(q, scale):
     return _dc.dequantize_rows(q, scale, interpret=_interpret())
+
+
+def quantize_rows(x):
+    """Row-wise absmax int8 of an (N, D) f32 array of any length: host
+    (q (N, D) int8, scale (N, 1) f32)."""
+    x = np.asarray(x, np.float32)
+    q, scale = _quantize_program(_pad(x, _bucket(len(x)), 0))
+    return np.asarray(q)[:len(x)], np.asarray(scale)[:len(x)]
+
+
+def dequantize_rows(q, scale):
+    """Inverse of ``quantize_rows``: host (N, D) f32."""
+    q = np.asarray(q)
+    nb = _bucket(len(q))
+    return np.asarray(_dequantize_program(_pad(q, nb, 0),
+                                          _pad(scale, nb, 1)))[:len(q)]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

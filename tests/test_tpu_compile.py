@@ -6,7 +6,9 @@ so a kernel the chip would refuse fails here, at no chip time. Nothing
 runs: ``chip_smoke.py`` is the run on the chip.
 
 ``ops`` picks interpret mode from ``jax.default_backend()``, which is the
-CPU here, so the ``mosaic`` fixture turns it off for these compiles.
+CPU here, so the ``mosaic`` fixture turns it off for these compiles. The
+PS entry points in ``ops`` pad host arrays around jitted programs; the
+programs are what is lowered here, at an already padded length.
 """
 
 import jax
@@ -78,6 +80,17 @@ def test_probe_compiles(one_chip, mosaic, cap):
     _check(c, kernel=True, below=8 * cap)
 
 
+@pytest.mark.parametrize("placement", ["vmem", "hbm"])
+@pytest.mark.parametrize("cap", [1 << 4, 1 << 12])
+def test_probe_small_map_compiles(one_chip, mosaic, cap, placement):
+    """Maps smaller than one probe window (the window clamps to the
+    capacity) and a few rows of 128 slots, each placement pinned."""
+    klo, khi, _, shift = _keys(one_chip, cap)
+    c = ops.hashmap_probe.lower(klo, khi, *_ids(one_chip), shift=shift,
+                                placement=placement).compile()
+    _check(c, kernel=True, below=N_IDS * 64)
+
+
 @pytest.mark.parametrize("d", [8, 1])
 def test_row_gather_scatter_compile(one_chip, d):
     """Row gather and the mirror's donated row scatter are XLA ops on the
@@ -88,8 +101,22 @@ def test_row_gather_scatter_compile(one_chip, d):
     gather = jax.jit(lambda a, s: jnp.take(a, s, axis=0, mode="clip"))
     _check(gather.lower(arena, slots).compile(), kernel=False,
            below=ARENA_ROWS * d * 4)
-    c = ops.embedding_scatter.lower(arena, slots, rows).compile()
+    c = ops._scatter_program.lower(arena, slots, rows).compile()
     _check(c, kernel=False, below=ARENA_ROWS * d * 4)
+
+
+def test_key_table_scatter_compiles(one_chip):
+    """The mirror's incremental key upload: flat slot positions scattered
+    into the donated ``(R, 128)`` key-limb table of a map past the VMEM
+    bound, and slots into its ``(cap,)`` slot map — in place, no
+    temporary the size of either table."""
+    klo, _, slot_of, _ = _keys(one_chip, HBM_CAP)
+    pos = _spec(one_chip, (N_IDS,), jnp.int32)
+    limbs = _spec(one_chip, (N_IDS,), jnp.uint32)
+    c = ops._scatter_program.lower(klo, pos, limbs).compile()
+    _check(c, kernel=False, below=klo.size * 4)
+    c = ops._scatter_program.lower(slot_of, pos, pos).compile()
+    _check(c, kernel=False, below=HBM_CAP * 4)
 
 
 @pytest.mark.parametrize("d", [8, 1])
@@ -103,7 +130,7 @@ def test_ftrl_row_update_compiles(one_chip, mosaic, d):
 def test_fused_lookup_compiles(one_chip, mosaic, d, cap):
     klo, khi, slot_of, shift = _keys(one_chip, cap)
     arena = _spec(one_chip, (ARENA_ROWS, d), jnp.float32)
-    c = ops.fused_lookup.lower(klo, khi, slot_of, arena, *_ids(one_chip),
+    c = ops._lookup_program.lower(klo, khi, slot_of, arena, *_ids(one_chip),
                                shift=shift).compile()
     _check(c, kernel=True, below=ARENA_ROWS * d * 4)
 
@@ -115,7 +142,7 @@ def test_fused_ftrl_apply_compiles(one_chip, mosaic, d, cap):
     klo, khi, slot_of, shift = _keys(one_chip, cap)
     arena = _spec(one_chip, (ARENA_ROWS, d), jnp.float32)
     grads = _spec(one_chip, (N_IDS, d), jnp.float32)
-    c = ops.fused_ftrl_apply.lower(
+    c = ops._ftrl_program.lower(
         klo, khi, slot_of, arena, arena, arena, *_ids(one_chip), grads,
         shift=shift, alpha=0.05, beta=1.0, l1=1.0, l2=1.0).compile()
     _check(c, kernel=True, below=ARENA_ROWS * d * 4)
@@ -124,9 +151,9 @@ def test_fused_ftrl_apply_compiles(one_chip, mosaic, d, cap):
 @pytest.mark.parametrize("d", [8, 1])
 def test_codec_pair_compiles(one_chip, mosaic, d):
     x = _spec(one_chip, (N_IDS, d), jnp.float32)
-    _check(ops.quantize_rows.lower(x).compile(), kernel=True,
+    _check(ops._quantize_program.lower(x).compile(), kernel=True,
            below=N_IDS * d * 4)
     q = _spec(one_chip, (N_IDS, d), jnp.int8)
     s = _spec(one_chip, (N_IDS, 1), jnp.float32)
-    _check(ops.dequantize_rows.lower(q, s).compile(), kernel=True,
+    _check(ops._dequantize_program.lower(q, s).compile(), kernel=True,
            below=N_IDS * d * 4)
