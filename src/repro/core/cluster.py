@@ -561,6 +561,9 @@ class WeiPSCluster:
         reg.register("replica_lag_skips",
                      lambda: sum(rs.lag_skips for rs in self.replica_sets))
         reg.register("device_mirror", self._device_mirror_metrics)
+        # process-wide: every cluster of the process shares the device
+        from repro.kernels.device_io import DEVICE_IO
+        reg.register("device_io", DEVICE_IO.metrics)
         self.serving.register_metrics(reg, prefix="serving")
         # one source of truth for the benchmark and the monitor:
         # joiner counters (late_feedback, join-delay percentiles),

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.hashmap import EMPTY as _NO_ID
 from repro.core.hashmap import IdHashMap
+from repro.obs import trace as obs_trace
 from repro.optim import Optimizer
 from repro.optim.optimizers import FTRL
 
@@ -473,19 +474,23 @@ class SparseTable:
         itself synced and the next batch uploads nothing but ids+grads.
         Returns the new serve weights ``w'`` for the rows."""
         from repro.kernels import ops
+        tr = obs_trace.get_tracer()
         mir = self._mirror()
-        mir.sync()
-        z_a, n_a, w_a, z2, n2, w2, found = ops.fused_ftrl_apply(
-            mir.keys_lo, mir.keys_hi, mir.slot_of,
-            mir.arenas["z"], mir.arenas["n"], mir.arenas["w"], ids, grads,
-            shift=mir.shift, alpha=alpha, beta=beta, l1=l1, l2=l2,
-            placement=mir.placement)
+        with tr.span("ps.mirror_sync"):
+            mir.sync()
+        with tr.span("ps.ftrl"):
+            z_a, n_a, w_a, z2, n2, w2, found = ops.fused_ftrl_apply(
+                mir.keys_lo, mir.keys_hi, mir.slot_of,
+                mir.arenas["z"], mir.arenas["n"], mir.arenas["w"], ids,
+                grads, shift=mir.shift, alpha=alpha, beta=beta, l1=l1,
+                l2=l2, placement=mir.placement)
         mir.arenas["z"], mir.arenas["n"], mir.arenas["w"] = z_a, n_a, w_a
         if not found.all():
             raise RuntimeError("fused_ftrl_update on ids absent from the "
                                "map (run ensure first)")
         w_np = w2.astype(self.dtype, copy=False)
-        self.write_rows(sl, w_np, {"z": z2, "n": n2}, step=step)
+        with tr.span("ps.write_back"):
+            self.write_rows(sl, w_np, {"z": z2, "n": n2}, step=step)
         mir.mark_synced()
         return w_np
 
@@ -652,27 +657,38 @@ class MasterShard:
         """The fused PS hot path: one batched hash → gather → optimizer
         update → scatter pass for a whole minibatch. Duplicate ids are
         deduplicated with their gradients summed (the correct sparse-grad
-        semantics). Returns the unique ids touched."""
+        semantics). Returns the unique ids touched. The pass is a
+        ``ps.apply`` span of ``repro.obs.trace``, its stages ``ps.*``
+        spans under it."""
+        with obs_trace.get_tracer().span("ps.apply"):
+            return self._apply_batch(group, ids, grads, step=step)
+
+    def _apply_batch(self, group: str, ids: np.ndarray, grads: np.ndarray,
+                     *, step: Optional[int]) -> np.ndarray:
         assert self.alive, f"master shard {self.shard_id} is down"
+        tr = obs_trace.get_tracer()
         t = self.tables[group]
         st = self.step if step is None else step
         ids = np.asarray(ids, dtype=np.int64)
         grads = np.asarray(grads, dtype=np.float32)
-        uniq, inv, counts = np.unique(ids, return_inverse=True,
-                                      return_counts=True)
-        if len(uniq) != len(ids):
-            # segment-sum duplicate-id grads (sort + reduceat: orders of
-            # magnitude faster than np.add.at's buffered scatter-add)
-            order = np.argsort(inv, kind="stable")
-            starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
-            grads = np.add.reduceat(
-                grads.take(order, axis=0, mode="clip"), starts, axis=0)
-        elif len(ids) > 1 and not (ids[1:] >= ids[:-1]).all():
-            # unique but unsorted: slots are resolved for sorted ``uniq``,
-            # so grad rows must be permuted to match
-            grads = grads.take(np.argsort(inv, kind="stable"), axis=0,
-                               mode="clip")
-        sl = t.ensure(uniq)
+        with tr.span("ps.dedup"):
+            uniq, inv, counts = np.unique(ids, return_inverse=True,
+                                          return_counts=True)
+            if len(uniq) != len(ids):
+                # segment-sum duplicate-id grads (sort + reduceat: orders
+                # of magnitude faster than np.add.at's buffered
+                # scatter-add)
+                order = np.argsort(inv, kind="stable")
+                starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+                grads = np.add.reduceat(
+                    grads.take(order, axis=0, mode="clip"), starts, axis=0)
+            elif len(ids) > 1 and not (ids[1:] >= ids[:-1]).all():
+                # unique but unsorted: slots are resolved for sorted
+                # ``uniq``, so grad rows must be permuted to match
+                grads = grads.take(np.argsort(inv, kind="stable"), axis=0,
+                                   mode="clip")
+        with tr.span("ps.ensure"):
+            sl = t.ensure(uniq)
         if (self.backend == "pallas" and isinstance(self.optimizer, FTRL)
                 and t.slot_names == ("n", "z")):
             # fused device route: ensure resolved/created the rows on the

@@ -216,9 +216,10 @@ class Pusher:
         ver = self.shard.dense.versions[name]
         # copy: identity encode passes arrays through uncopied, and a
         # queued payload must never alias the live dense tensor
-        payload = self.transform.encode(
-            value.reshape(1, -1).copy(),
-            self.shard.dense.slots.get(name, {}))
+        with obs_trace.get_tracer().span("sync.encode"):
+            payload = self.transform.encode(
+                value.reshape(1, -1).copy(),
+                self.shard.dense.slots.get(name, {}))
         meta = {"codec": self.transform.name, "t": now,
                 "shape": value.shape}
         if self._tmeta is not None:
@@ -262,7 +263,8 @@ class Pusher:
             w, slots = table.gather(
                 ids, want_w=self.transform.requires_w,
                 slot_names=self.transform.required_slots)
-            payload = self.transform.encode(w, slots)
+            with obs_trace.get_tracer().span("sync.encode"):
+                payload = self.transform.encode(w, slots)
         n = 0
         for s, e in zip(starts, ends):
             p = int(part[s])

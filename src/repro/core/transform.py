@@ -38,6 +38,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
 from repro.optim import FTRL, Optimizer
 
 CODEC_BACKENDS = ("numpy", "pallas")
@@ -211,6 +212,8 @@ def make_transform(codec: str, optimizer: Optional[Optimizer] = None,
 def decode_record(record, backend: str = "numpy") -> np.ndarray:
     """Consumer-side decode: codec resolved from ``record.meta["codec"]``
     (defaulting to identity for pre-codec records), backend chosen by the
-    *consumer* — producer and consumer backends are independent."""
+    *consumer* — producer and consumer backends are independent. A
+    ``sync.decode`` span of ``repro.obs.trace``."""
     codec = record.meta.get("codec", "identity")
-    return _TRANSFORMS[codec].decode(record.payload, backend=backend)
+    with obs_trace.get_tracer().span("sync.decode"):
+        return _TRANSFORMS[codec].decode(record.payload, backend=backend)

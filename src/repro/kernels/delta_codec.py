@@ -48,6 +48,7 @@ def quantize_rows(x: jax.Array, *, block_rows: int = 256,
         out_shape=[jax.ShapeDtypeStruct((b, d), jnp.int8),
                    jax.ShapeDtypeStruct((b, 1), jnp.float32)],
         interpret=interpret,
+        name="quantize_rows",
     )(x)
 
 
@@ -69,4 +70,5 @@ def dequantize_rows(q: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
         interpret=interpret,
+        name="dequantize_rows",
     )(q, scale)

@@ -54,4 +54,5 @@ def ftrl_row_update(z: jax.Array, n: jax.Array, g: jax.Array, *,
         out_specs=[spec, spec, spec],
         out_shape=[out, out, out],
         interpret=interpret,
+        name="ftrl_row_update",
     )(z.astype(jnp.float32), n.astype(jnp.float32), g.astype(jnp.float32))

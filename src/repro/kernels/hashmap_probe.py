@@ -312,6 +312,7 @@ def _probe_pass(klo, khi, qlo, qhi, pos, found, active, cur, first, *,
         grid_spec=grid_spec,
         out_shape=[col_i32] * 4,
         interpret=interpret,
+        name="hashmap_probe",
     )(first, starts, starts, klo, khi, qlo, qhi, pos, found, active, cur)
 
 

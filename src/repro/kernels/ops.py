@@ -15,7 +15,9 @@ The PS entry points (``embedding_scatter``, ``fused_lookup``,
 ``fused_gather``, ``fused_ftrl_apply``, ``quantize_rows``,
 ``dequantize_rows``) take host arrays of any length, pad them to a few
 power-of-two lengths — every distinct length compiles its own program —
-and return unpadded results.
+and return unpadded results. What they hand to the device and read back
+is counted, and each blocking read is a span, through
+``kernels/device_io.py``.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ from repro.kernels import delta_codec as _dc
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ftrl_row_update as _ftrl
 from repro.kernels import hashmap_probe as _hm
+from repro.kernels.device_io import count_h2d, to_host
 
 
 def _interpret() -> bool:
@@ -134,20 +137,25 @@ def fused_lookup(keys_lo, keys_hi, slot_of, arena, ids, *, shift,
     slots let them update host-side LRU stats, neither costs a host
     re-probe."""
     n = len(ids)
+    limbs = _id_limbs(ids, _bucket(n))
+    count_h2d(*limbs)
     rows, found, slot = _lookup_program(
-        keys_lo, keys_hi, slot_of, arena, *_id_limbs(ids, _bucket(n)),
+        keys_lo, keys_hi, slot_of, arena, *limbs,
         shift=shift, placement=placement)
-    return rows[:n], np.asarray(found)[:n], np.asarray(slot)[:n]
+    found, slot = to_host(found, slot)
+    return rows[:n], found[:n], slot[:n]
 
 
 def fused_gather(keys_lo, keys_hi, slot_of, arena, ids, *, shift,
                  placement="auto"):
     """``fused_lookup``'s rows as a host array. Sliced on the host: a
     device slice compiles a program for every id count."""
+    limbs = _id_limbs(ids, _bucket(len(ids)))
+    count_h2d(*limbs)
     rows, _, _ = _lookup_program(
-        keys_lo, keys_hi, slot_of, arena, *_id_limbs(ids, _bucket(len(ids))),
+        keys_lo, keys_hi, slot_of, arena, *limbs,
         shift=shift, placement=placement)
-    return np.asarray(rows)[:len(ids)]
+    return to_host(rows)[0][:len(ids)]
 
 
 @functools.partial(jax.jit,
@@ -191,13 +199,14 @@ def fused_ftrl_apply(keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
     re-downloading whole arenas."""
     n = len(ids)
     nb = _bucket(n)
+    ins = (*_id_limbs(ids, nb), _pad(np.asarray(grads, np.float32), nb, 0))
+    count_h2d(*ins)
     z_a, n_a, w_a, z2, n2, w2, found = _ftrl_program(
-        keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena,
-        *_id_limbs(ids, nb), _pad(np.asarray(grads, np.float32), nb, 0),
+        keys_lo, keys_hi, slot_of, z_arena, n_arena, w_arena, *ins,
         shift=shift, alpha=alpha, beta=beta, l1=l1, l2=l2,
         placement=placement)
-    return (z_a, n_a, w_a, np.asarray(z2)[:n], np.asarray(n2)[:n],
-            np.asarray(w2)[:n], np.asarray(found)[:n])
+    z2, n2, w2, found = to_host(z2, n2, w2, found)
+    return z_a, n_a, w_a, z2[:n], n2[:n], w2[:n], found[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "beta", "l1", "l2"))
@@ -220,16 +229,19 @@ def quantize_rows(x):
     """Row-wise absmax int8 of an (N, D) f32 array of any length: host
     (q (N, D) int8, scale (N, 1) f32)."""
     x = np.asarray(x, np.float32)
-    q, scale = _quantize_program(_pad(x, _bucket(len(x)), 0))
-    return np.asarray(q)[:len(x)], np.asarray(scale)[:len(x)]
+    xp = _pad(x, _bucket(len(x)), 0)
+    count_h2d(xp)
+    q, scale = to_host(*_quantize_program(xp))
+    return q[:len(x)], scale[:len(x)]
 
 
 def dequantize_rows(q, scale):
     """Inverse of ``quantize_rows``: host (N, D) f32."""
     q = np.asarray(q)
     nb = _bucket(len(q))
-    return np.asarray(_dequantize_program(_pad(q, nb, 0),
-                                          _pad(scale, nb, 1)))[:len(q)]
+    ins = (_pad(q, nb, 0), _pad(scale, nb, 1))
+    count_h2d(*ins)
+    return to_host(_dequantize_program(*ins))[0][:len(q)]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

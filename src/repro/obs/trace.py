@@ -5,7 +5,6 @@ Design constraints, in order:
 * **~zero cost disabled.** The module-global tracer starts disabled;
   hot paths guard with ``if tr.enabled:`` (one attribute read) or call
   ``tr.begin(...)`` unconditionally and get back a shared no-op span.
-  `benchmarks/obs_overhead.py` gates both regimes.
 * **Low overhead enabled.** Spans land in a preallocated ring buffer of
   plain tuples — no allocation beyond the tuple itself, no locks (each
   OS process owns its tracer; the runtime merges exports), no I/O until
@@ -18,6 +17,13 @@ Design constraints, in order:
   into ``Record.meta``, which crosses the FileQueue for free (records
   are whole-pickled frames), letting the consumer reconstruct the
   queue-dwell span and parent the apply under it.
+* **On the profiler's clock when asked.** With ``annotate=True`` each
+  ``begin``/``end`` pair also enters and exits a
+  ``jax.profiler.TraceAnnotation`` under the span's bare name (no
+  attributes, so the names stay stable), so a ``jax.profiler`` trace
+  holds the spans on the same timeline as the device's ops. jax is
+  imported only then; ``record()`` spans, whose timestamps are given
+  after the fact, stay in the ring alone.
 
 Run ``python -m repro.obs.trace dump.json`` to summarize an exported
 trace: per-stage span counts and p50/p99 durations, plus the slowest
@@ -50,7 +56,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "trace", "id", "parent", "t0", "attrs")
+    __slots__ = ("_tracer", "name", "trace", "id", "parent", "t0", "attrs",
+                 "ann")
 
     def __enter__(self):
         return self
@@ -66,7 +73,8 @@ class Tracer:
     Spans are stored as ``(name, trace, span, parent, t0, t1, attrs)``
     tuples; ``t1 is None`` marks an instant annotation. ``export()``
     returns dicts in ring order (oldest first) tagged with this
-    tracer's process name.
+    tracer's process name. ``annotate`` mirrors every ``begin``/``end``
+    span into the ``jax.profiler`` trace (module docstring).
     """
 
     def __init__(
@@ -76,8 +84,13 @@ class Tracer:
         clock: Optional[Callable[[], float]] = None,
         process: str = "main",
         enabled: bool = True,
+        annotate: bool = False,
     ):
         self.enabled = enabled
+        self._annotation = None
+        if annotate and enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.clock = clock or time.perf_counter
         self.process = process
         self.capacity = int(capacity)
@@ -133,6 +146,10 @@ class Tracer:
         sp.attrs = attrs or None
         self._ctx.append((trace, sp.id))
         self._open[sp.id] = sp
+        sp.ann = None
+        if self._annotation is not None:
+            sp.ann = self._annotation(name)
+            sp.ann.__enter__()
         sp.t0 = self.clock()
         return sp
 
@@ -142,6 +159,9 @@ class Tracer:
         if sp is _NULL_SPAN:
             return
         t1 = self.clock()
+        if sp.ann is not None:
+            sp.ann.__exit__(None, None, None)
+            sp.ann = None
         self._open.pop(sp.id, None)
         if self._ctx:
             if self._ctx[-1][1] == sp.id:          # common case: LIFO
@@ -233,11 +253,12 @@ def get_tracer() -> Tracer:
 
 def configure(*, enabled: bool = True, capacity: int = 1 << 15,
               clock: Optional[Callable[[], float]] = None,
-              process: str = "main") -> Tracer:
-    """Install (and return) a fresh process-global tracer."""
+              process: str = "main", annotate: bool = False) -> Tracer:
+    """Install (and return) a fresh process-global tracer; ``annotate``
+    also writes its spans into an active ``jax.profiler`` trace."""
     global _tracer
     _tracer = Tracer(capacity=capacity, clock=clock, process=process,
-                     enabled=enabled)
+                     enabled=enabled, annotate=annotate)
     return _tracer
 
 

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.data.joiner import JoinedBatch, SampleJoiner
 from repro.data.streams import EventBatch
+from repro.obs import trace as obs_trace
 from repro.training.plane import TrainingPlane
 from repro.training.registry import TrainScenario
 
@@ -132,12 +133,15 @@ class TrainPipeline:
         """Deliver matured feedback, drain the join window into the
         buffer, then train full buckets (every remaining sample too,
         padded, when ``flush``). Throttles — trains nothing — while the
-        sync plane's lag exceeds the bound."""
-        self._deliver_feedback(now)       # before the expiry sweep: a
-        # click due at ``now`` beats a window that closes at ``now``
-        drained = self.joiner.drain_batch(now)
-        if len(drained):
-            self._buffer(drained)
+        sync plane's lag exceeds the bound. The join work and each
+        ``_take`` are ``train.drain`` spans."""
+        tr = obs_trace.get_tracer()
+        with tr.span("train.drain"):
+            self._deliver_feedback(now)   # before the expiry sweep: a
+            # click due at ``now`` beats a window that closes at ``now``
+            drained = self.joiner.drain_batch(now)
+            if len(drained):
+                self._buffer(drained)
         if self.max_sync_lag is not None and self.lag_fn is not None \
                 and self.lag_fn() > self.max_sync_lag:
             self.throttled_ticks += 1
@@ -146,7 +150,8 @@ class TrainPipeline:
         top = self.buckets[-1]
         while self._buffered >= self.buckets[0] or \
                 (flush and self._buffered):
-            ids, y, w = self._take(min(self._buffered, top))
+            with tr.span("train.drain"):
+                ids, y, w = self._take(min(self._buffered, top))
             out.append(self.plane.train_batch(
                 self.scn, ids, y, weights=w, now=now,
                 bucket=self.bucket_for(len(ids))))

@@ -41,7 +41,9 @@ import numpy as np
 from repro.configs.weips_ctr import CTRConfig
 from repro.core.feature_filter import FeatureFilter
 from repro.core.routing import RoutingPlan
+from repro.kernels.device_io import count_h2d, to_host
 from repro.models import ctr as ctr_model
+from repro.obs import trace as obs_trace
 from repro.optim import Optimizer
 from repro.serving.router import RowRouter
 from repro.training.registry import TrainRegistry, TrainScenario
@@ -148,6 +150,18 @@ class TrainingPlane:
         optimizer. ``bucket`` pads rows/labels/weights up to that example
         count (padding weight 0) so the jitted fns compile once per
         bucket shape."""
+        with obs_trace.get_tracer().span("train.batch"):
+            return self._train_batch(scn, ids, y, now=now, weights=weights,
+                                     bucket=bucket)
+
+    def _train_batch(self, scn: TrainScenario, ids: np.ndarray,
+                     y: np.ndarray, *, now: float,
+                     weights: Optional[np.ndarray],
+                     bucket: Optional[int]) -> dict:
+        """``train_batch``'s body; each stage is a span of
+        ``repro.obs.trace`` (``train.dedup`` … ``train.grad_agg``), the
+        masters' updates nest their own ``ps.*`` spans."""
+        tr = obs_trace.get_tracer()
         ids = np.asarray(ids, dtype=np.int64)
         b, f = ids.shape
         y = np.asarray(y, np.float32)
@@ -155,66 +169,71 @@ class TrainingPlane:
             np.asarray(weights, np.float32)
 
         # ONE dedup serves admission, pull, and push
-        uniq, inverse = RowRouter.unique(ids)
-        scn.stats.raw_ids += ids.size
-        scn.stats.unique_ids += len(uniq)
-        admitted = self.filter.admit(uniq) if self.filter is not None \
-            else uniq
+        with tr.span("train.dedup"):
+            uniq, inverse = RowRouter.unique(ids)
+            scn.stats.raw_ids += ids.size
+            scn.stats.unique_ids += len(uniq)
+            admitted = self.filter.admit(uniq) if self.filter is not None \
+                else uniq
 
-        vals = self.pull_unique(scn, uniq)
-        rows = RowRouter.expand(vals, inverse, (b, f))
+        with tr.span("train.pull"):
+            vals = self.pull_unique(scn, uniq)
+            rows = RowRouter.expand(vals, inverse, (b, f))
+            nb = b if bucket is None or bucket < b else bucket
+            if nb > b:
+                pad = nb - b
+                rows = {g: np.concatenate(
+                    [v, np.zeros((pad,) + v.shape[1:], v.dtype)]) for g, v
+                    in rows.items()}
+                y_in = np.concatenate([y, np.zeros(pad, np.float32)])
+                w_in = np.concatenate([w, np.zeros(pad, np.float32)])
+                scn.stats.padded_examples += pad
+                scn.stats.bucket_counts[nb] = \
+                    scn.stats.bucket_counts.get(nb, 0) + 1
+            else:
+                y_in, w_in = y, w
 
-        nb = b if bucket is None or bucket < b else bucket
-        if nb > b:
-            pad = nb - b
-            rows = {g: np.concatenate(
-                [v, np.zeros((pad,) + v.shape[1:], v.dtype)]) for g, v
-                in rows.items()}
-            y_in = np.concatenate([y, np.zeros(pad, np.float32)])
-            w_in = np.concatenate([w, np.zeros(pad, np.float32)])
-            scn.stats.padded_examples += pad
-            scn.stats.bucket_counts[nb] = \
-                scn.stats.bucket_counts.get(nb, 0) + 1
-        else:
-            y_in, w_in = y, w
-        rows_j = {k: jnp.asarray(v) for k, v in rows.items()}
-        dense_j = {k: jnp.asarray(v) for k, v in scn.dense.items()}
-
-        # progressive validation (predict BEFORE applying the update);
-        # padded rows are sliced off — the metrics never see them
-        p = np.asarray(scn.predict(rows_j, dense_j))[:b]
-        point = scn.validator.observe(now, scn.step, y, p)
-        scn.evaluator.observe(now, scn.step, y, p, weights=w)
-
-        loss, row_grads, dense_grads = scn.loss_grads(
-            rows_j, dense_j, jnp.asarray(y_in), jnp.asarray(w_in))
+        with tr.span("train.forward"):
+            count_h2d(*rows.values(), *scn.dense.values(), y_in, w_in)
+            rows_j = {k: jnp.asarray(v) for k, v in rows.items()}
+            dense_j = {k: jnp.asarray(v) for k, v in scn.dense.items()}
+            # progressive validation (predict BEFORE applying the update);
+            # padded rows are sliced off — the metrics never see them
+            p = to_host(scn.predict(rows_j, dense_j))[0][:b]
+            point = scn.validator.observe(now, scn.step, y, p)
+            scn.evaluator.observe(now, scn.step, y, p, weights=w)
+            loss, row_grads, dense_grads = scn.loss_grads(
+                rows_j, dense_j, jnp.asarray(y_in), jnp.asarray(w_in))
 
         # aggregate per-row grads over duplicate ids, push to owner
         # masters; non-admitted ids are dropped BEFORE the push, so they
         # never create rows (padding rows carry weight 0 → zero grads,
         # and the [:b] slice drops them from the aggregation entirely)
-        if self.filter is not None and len(admitted) != len(uniq):
-            keep = np.isin(uniq, admitted, assume_unique=True)
-        else:
-            keep = None
-        by_master = self.plan.split_by_master(
-            uniq if keep is None else uniq[keep])
+        with tr.span("train.grad_agg"):
+            if self.filter is not None and len(admitted) != len(uniq):
+                keep = np.isin(uniq, admitted, assume_unique=True)
+            else:
+                keep = None
+            by_master = self.plan.split_by_master(
+                uniq if keep is None else uniq[keep])
         for group, g in row_grads.items():
-            g = np.asarray(g)[:b].reshape(-1, g.shape[-1])    # (B*F, dim)
-            agg = np.zeros((len(uniq), g.shape[-1]), np.float32)
-            np.add.at(agg, inverse, g)
+            with tr.span("train.grad_agg"):
+                g = to_host(g)[0][:b].reshape(-1, g.shape[-1])  # (B*F, dim)
+                agg = np.zeros((len(uniq), g.shape[-1]), np.float32)
+                np.add.at(agg, inverse, g)
+                parts = [(mid, mids, agg[np.searchsorted(uniq, mids)])
+                         for mid, mids in by_master.items()]
             store_g = scn.group_map[group]
-            for mid, mids in by_master.items():
-                pos = np.searchsorted(uniq, mids)
-                self.masters[mid].push_grad(store_g, mids, agg[pos],
-                                            step=scn.step)
+            for mid, mids, gm in parts:
+                self.masters[mid].push_grad(store_g, mids, gm, step=scn.step)
         # dense updates (DNN head) on master shard 0
         if dense_grads:
             for dn, g in dense_grads.items():
+                count_h2d(scn.dense[dn])
                 new_w, new_slots = self.optimizer.update(
                     jnp.asarray(scn.dense[dn]), scn.dense_slots[dn],
                     g, scn.step)
-                scn.dense[dn] = np.asarray(new_w)
+                scn.dense[dn] = to_host(new_w)[0]
                 scn.dense_slots[dn] = new_slots
                 self.masters[0].push_dense(scn.dense_store_name(dn),
                                            scn.dense[dn])
@@ -222,7 +241,7 @@ class TrainingPlane:
         scn.step += 1
         scn.stats.batches += 1
         scn.stats.examples += b
-        return {"loss": float(loss), **point.values}
+        return {"loss": float(to_host(loss)[0]), **point.values}
 
     # ------------------------------------------------------------------
     # metrics

@@ -20,6 +20,9 @@ device_mirror.key_full_uploads
 device_mirror.key_incremental_uploads
 device_mirror.syncs
 device_mirror.tables
+device_io.d2h_bytes
+device_io.h2d_bytes
+device_io.waits
 pushed_bytes
 queue_bytes
 replica_failovers
@@ -126,7 +129,7 @@ def test_sync_metrics_top_level_schema(driven_cluster):
         "sync_lag_seconds", "staleness", "sync_lag_records",
         "pushed_bytes", "queue_bytes", "dedup_ratio",
         "replica_failovers", "replica_lag_skips", "device_mirror",
-        "serving", "training"}
+        "device_io", "serving", "training"}
     assert set(m["staleness"]) == {"p50", "p99"}
     assert isinstance(m["serving"]["scenarios"], dict)
     assert isinstance(m["training"]["scenarios"], dict)

@@ -134,6 +134,68 @@ class TestTracer:
 
 
 # ---------------------------------------------------------------------
+# spans on the profiler's clock (annotate=True)
+# ---------------------------------------------------------------------
+def _profile_events(tmp_path, body) -> list:
+    """Run ``body`` inside a ``jax.profiler`` session; the host events
+    of the written ``.xplane.pb`` as (name, start_ns, end_ns)."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+class TestAnnotate:
+    def test_nested_and_out_of_order_spans_reach_the_profile(self, tmp_path):
+        tr = obs_trace.configure(enabled=True, annotate=True)
+
+        def body():
+            with tr.span("test.outer", rows=3):
+                with tr.span("test.inner"):
+                    pass
+            a = tr.begin("test.first")
+            b = tr.begin("test.second")
+            tr.end(a)                       # ends before its child
+            tr.end(b)
+
+        ev = {n: (t0, t1) for n, t0, t1 in _profile_events(tmp_path, body)
+              if n.startswith("test.")}
+        # bare names: attributes stay in the ring, not in the profile
+        assert set(ev) == {"test.outer", "test.inner", "test.first",
+                           "test.second"}
+        assert ev["test.outer"][0] <= ev["test.inner"][0]
+        assert ev["test.inner"][1] <= ev["test.outer"][1]
+        assert ev["test.first"][0] <= ev["test.second"][0]
+        assert ev["test.first"][1] <= ev["test.second"][1]
+        ring = {s["name"]: s for s in tr.export()}
+        assert ring["test.outer"]["args"] == {"rows": 3}
+        assert ring["test.second"]["parent"] == ring["test.first"]["span"]
+
+    @pytest.mark.parametrize("enabled,annotate", [(False, True),
+                                                  (True, False)])
+    def test_no_annotation_unless_enabled_and_asked(self, monkeypatch,
+                                                    enabled, annotate):
+        import jax.profiler
+        made = []
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda name: made.append(name))
+        tr = obs_trace.configure(enabled=enabled, annotate=annotate)
+        with tr.span("x"):
+            pass
+        tr.end(tr.begin("y"))
+        assert made == []
+        assert (tr.begin("z") is obs_trace._NULL_SPAN) == (not enabled)
+
+
+# ---------------------------------------------------------------------
 # perfetto
 # ---------------------------------------------------------------------
 class TestPerfetto:

@@ -275,3 +275,30 @@ def test_mirror_incremental_key_sync_counters(rng):
     m2 = st.mirror_metrics()
     assert m2["key_full_uploads"] == 1
     assert m2["key_incremental_uploads"] == 2
+
+
+def test_fused_ftrl_apply_counts_device_io(rng):
+    """A tiny fused FTRL call adds its padded inputs to ``h2d_bytes``, its
+    four row and mask outputs to ``d2h_bytes``, and one blocking read."""
+    from repro.kernels import hashmap_probe as hm
+    from repro.kernels import ops
+    from repro.kernels.device_io import DEVICE_IO
+    t = SparseTable(DIM, ("n", "z"), backend="pallas")
+    ids = np.sort(_rand_ids(rng, 5, space=100))
+    sl = t.ensure(ids)
+    mir = t._mirror()
+    mir.sync()
+    grads = np.ones((5, DIM), np.float32)
+    before = DEVICE_IO.metrics()
+    out = ops.fused_ftrl_apply(
+        mir.keys_lo, mir.keys_hi, mir.slot_of, mir.arenas["z"],
+        mir.arenas["n"], mir.arenas["w"], ids, grads, shift=mir.shift,
+        alpha=0.1, beta=1.0, l1=0.5, l2=0.2, placement=mir.placement)
+    after = DEVICE_IO.metrics()
+    nb = hm.OFFSET_BLOCK                # 5 ids pad to one probe granule
+    assert after["h2d_bytes"] - before["h2d_bytes"] == \
+        2 * nb * 4 + nb * DIM * 4       # id limbs + grads
+    assert after["d2h_bytes"] - before["d2h_bytes"] == \
+        3 * nb * DIM * 4 + nb           # z', n', w' + found mask
+    assert after["waits"] - before["waits"] == 1
+    assert out[-1].all() and len(sl) == 5

@@ -11,6 +11,8 @@ PS entry points in ``ops`` pad host arrays around jitted programs; the
 programs are what is lowered here, at an already padded length.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -157,3 +159,31 @@ def test_codec_pair_compiles(one_chip, mosaic, d):
     s = _spec(one_chip, (N_IDS, 1), jnp.float32)
     _check(ops._dequantize_program.lower(q, s).compile(), kernel=True,
            below=N_IDS * d * 4)
+
+
+def _kernel_names(compiled) -> set:
+    """Instruction names (less the ``.N`` suffix) of the Pallas calls in
+    a compiled program: the names the profiler's op events carry."""
+    return {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%(\S+?)(?:\.\d+)? = .*"
+        r"custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text(), re.M)}
+
+
+def test_kernels_keep_their_names(one_chip, mosaic):
+    """Each ``pallas_call`` passes ``name=``, so the probe no longer shows
+    as the loop body it sits in."""
+    klo, khi, slot_of, shift = _keys(one_chip, 1 << 12)
+    arena = _spec(one_chip, (1 << 14, 8), jnp.float32)
+    grads = _spec(one_chip, (N_IDS, 8), jnp.float32)
+    c = ops._ftrl_program.lower(
+        klo, khi, slot_of, arena, arena, arena, *_ids(one_chip), grads,
+        shift=shift, alpha=0.05, beta=1.0, l1=1.0, l2=1.0).compile()
+    assert _kernel_names(c) == {"hashmap_probe", "ftrl_row_update"}
+    x = _spec(one_chip, (N_IDS, 8), jnp.float32)
+    assert _kernel_names(ops._quantize_program.lower(x).compile()) == \
+        {"quantize_rows"}
+    q = _spec(one_chip, (N_IDS, 8), jnp.int8)
+    sc = _spec(one_chip, (N_IDS, 1), jnp.float32)
+    assert _kernel_names(ops._dequantize_program.lower(q, sc).compile()) \
+        == {"dequantize_rows"}

@@ -316,3 +316,51 @@ def test_dnn_scenario_trains_through_pipeline():
     scn = cl.training.scenario()
     assert scn.evaluator.smoothed("auc", window=10) > 0.55
     assert pipe.joiner.fast_emits > 0
+
+
+# ---------------------------------------------------------------------------
+# spans of the train step
+# ---------------------------------------------------------------------------
+def test_train_batch_span_tree():
+    """One pallas-backed step (interpret mode on the CPU) traces as
+    train.batch ⊃ {dedup, pull, forward, grad_agg ⊃ device.wait,
+    ps.apply ⊃ {dedup, ensure, mirror_sync, ftrl ⊃ device.wait,
+    write_back}}, on the tracer's implicit parenting."""
+    from repro.obs import trace as obs_trace
+    cl = WeiPSCluster(LR_FTRL, ClusterConfig(
+        num_master=2, num_slave=1, num_replicas=1, num_partitions=2,
+        ps_backend="pallas"))
+    scn = cl.training.scenario()
+    ids = np.arange(8 * LR_FTRL.fields, dtype=np.int64).reshape(8, -1)
+    cl.training.train_batch(scn, ids, np.ones(8, np.float32))  # warm
+    tr = obs_trace.configure(enabled=True)
+    try:
+        cl.training.train_batch(scn, ids, np.zeros(8, np.float32))
+        spans = tr.export()
+    finally:
+        obs_trace.disable()
+    by_id = {s["span"]: s for s in spans}
+
+    def ancestors(s):
+        out = []
+        while s["parent"] in by_id:
+            s = by_id[s["parent"]]
+            out.append(s["name"])
+        return out
+
+    roots = [s for s in spans if not ancestors(s)]
+    assert [s["name"] for s in roots] == ["train.batch"]
+    under = {}
+    for s in spans:
+        a = ancestors(s)
+        under.setdefault(s["name"], set()).add(a[0] if a else None)
+    assert under["train.dedup"] == under["train.pull"] == \
+        under["train.forward"] == under["train.grad_agg"] == \
+        under["ps.apply"] == {"train.batch"}
+    assert under["device.wait"] == {"train.forward", "train.grad_agg",
+                                    "ps.ftrl", "train.batch"}
+    for name in ("ps.dedup", "ps.ensure", "ps.mirror_sync", "ps.ftrl",
+                 "ps.write_back"):
+        assert under[name] == {"ps.apply"}, name
+    # both masters take a share of each group's rows (LR: one group)
+    assert sum(s["name"] == "ps.apply" for s in spans) == 2
