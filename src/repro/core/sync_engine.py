@@ -20,8 +20,8 @@ bandwidth/staleness trade the paper's full-value-per-ID consistency
 contract makes safe (skipped pushes are never *wrong*, only stale).
 
 Backends: ``SyncConfig.codec_backend="pallas"`` routes the int8 codec's
-quantize/dequantize through the ``delta_codec`` kernel
-(``docs/KERNELS.md``) — bit-identical to the numpy mirror, so producer
+quantize through the ``delta_codec`` kernel (``docs/KERNELS.md``) and
+dequantizes on the host — bit-identical to the numpy mirror, so producer
 and consumer may run different backends. The model states synced here
 are dense jax pytrees, not PS tables, so the sparse fused path
 (probe→gather→update→scatter, ``ClusterConfig.ps_backend``) does not

@@ -11,25 +11,31 @@ Codecs:
   * identity    — serve weights as-is (fp32)
   * cast16      — fp16 cast (half bandwidth)
   * int8        — row-wise absmax int8 quantization (the Pallas
-                  ``delta_codec`` kernel is the TPU version of this path)
+                  ``delta_codec`` kernel is the TPU version of its encode)
   * ftrl        — the heterogeneous-parameter case: encode reads slots
                   (z, n) and ships the *derived* w
 
 Backends — mirroring the PS row engine's ``numpy|pallas`` switch:
   * ``numpy``   — CPU reference codecs (the fast path on CPU-only hosts);
-  * ``pallas``  — the int8 path routes through the ``delta_codec`` Pallas
-    kernel (``kernels.ops.quantize_rows``/``dequantize_rows``): interpret
-    mode off-TPU (bit-matching the reference), Mosaic-compiled on TPU.
-    Codecs without a kernel (identity, cast16) keep running the numpy
-    engine end-to-end (``kernel_backed`` gates the routing) — never an
-    error, and never a silent regression to eager-jnp — so cluster
-    configs can flip one flag for the whole sync plane.
+  * ``pallas``  — the int8 encode routes through the ``delta_codec``
+    Pallas kernel (``kernels.ops.quantize_rows``): interpret mode off-TPU
+    (bit-matching the reference), Mosaic-compiled on TPU. Codecs without
+    a kernel (identity, cast16) keep running the numpy engine end-to-end
+    (``kernel_backed`` gates the routing) — never an error, and never a
+    silent regression to eager-jnp — so cluster configs can flip one flag
+    for the whole sync plane.
+
+Decode runs on the host for every backend: a record's payload arrives as
+host numpy and its rows go straight into host tables, so a device round
+trip would buy nothing. The int8 decode is one f32 cast and one f32
+multiply, bit-identical to ``kernels.ops.dequantize_rows``, which stays
+for callers that hold device arrays.
 
 ``encode`` is backend-routed per *instance* (the pusher owns a configured
-``Transform``); ``decode`` is backend-routed per *call* (the scatter
-resolves the codec class from record metadata and passes its own
-backend), so producer and consumer backends are independent — exactly the
-paper's heterogeneous training/serving cluster split.
+``Transform``); ``decode`` is resolved per *record* (the scatter looks the
+codec class up from record metadata), so producer and consumer need not
+share a backend — exactly the paper's heterogeneous training/serving
+cluster split.
 """
 
 from __future__ import annotations
@@ -159,9 +165,10 @@ class Cast16Transform(Transform):
 
 class Int8Transform(Transform):
     """Row-wise absmax int8: 4x bandwidth reduction on the push stage.
-    ``backend="pallas"`` runs the actual ``kernels/delta_codec.py`` kernel;
-    ``numpy`` is its CPU mirror (bit-compatible by construction — the
-    kernel body is the same arithmetic)."""
+    ``backend="pallas"`` encodes with the ``kernels/delta_codec.py``
+    kernel; ``numpy`` is its CPU mirror (bit-compatible by construction —
+    the kernel body is the same arithmetic). Decode is host numpy on
+    either backend, bit-identical to the kernel's dequantize."""
 
     name = "int8"
     kernel_backed = True
@@ -186,11 +193,7 @@ class Int8Transform(Transform):
 
     @staticmethod
     def decode(payload, backend: str = "numpy"):
-        q = payload["q"]
-        if backend == "pallas" and q.size:
-            from repro.kernels import ops
-            return ops.dequantize_rows(q, payload["scale"])
-        return q.astype(np.float32) * payload["scale"]
+        return payload["q"].astype(np.float32) * payload["scale"]
 
 
 _TRANSFORMS: dict[str, type[Transform]] = {

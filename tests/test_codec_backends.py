@@ -6,6 +6,7 @@ test_transform.py, which is skipped wholesale when hypothesis is absent.)
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import Int8Transform, make_transform
 from repro.optim import FTRL
@@ -80,3 +81,35 @@ def test_encode_blocking_matches_unblocked():
             np.testing.assert_array_equal(np.asarray(blocked[key])[:1],
                                           np.asarray(single[key]))
             assert np.asarray(blocked[key]).shape[0] == n
+
+
+@pytest.mark.parametrize("n", [1, 1023, 1025, 4097])
+def test_int8_pallas_decode_is_host_and_bit_exact(n, monkeypatch):
+    """``Int8Transform.decode(backend="pallas")`` runs on the host: no call
+    into ``ops.dequantize_rows``, and the same bits as that kernel
+    (interpret mode) and the jnp oracle, at the device path's bucket
+    edges. A random block puts a ±127 code in every row; an all-zero
+    block decodes through the 1e-12 scale floor."""
+    from repro.kernels import ops, ref
+    rng = np.random.default_rng(n)
+    kernel = ops.dequantize_rows
+
+    def no_kernel(*_):
+        raise AssertionError("decode went through ops.dequantize_rows")
+    monkeypatch.setattr(ops, "dequantize_rows", no_kernel)
+    for w in ((rng.normal(size=(n, 8)) * 10).astype(np.float32),
+              np.zeros((n, 8), np.float32)):
+        enc = Int8Transform(backend="pallas").encode(w, {})
+        q, scale = enc["q"], enc["scale"]
+        if w.any():
+            assert (np.abs(q).max(axis=-1) == 127).all()
+        else:
+            assert (scale == np.float32(1e-12)).all()
+        got = Int8Transform.decode(enc, backend="pallas")
+        assert got.dtype == np.float32 and got.shape == (n, 8)
+        bits = got.view(np.uint32)
+        np.testing.assert_array_equal(
+            bits, np.asarray(kernel(q, scale)).view(np.uint32))
+        np.testing.assert_array_equal(
+            bits, np.asarray(ref.dequantize_rows(
+                jnp.asarray(q), jnp.asarray(scale))).view(np.uint32))

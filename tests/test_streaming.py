@@ -188,6 +188,45 @@ def test_pallas_numpy_backends_bit_compatible(codec):
     np.testing.assert_array_equal(decoded["numpy"], decoded["pallas"])
 
 
+def test_pallas_replica_poll_makes_no_device_read():
+    """A replica with ``codec_backend="pallas"`` decodes the int8 records
+    of a poll on the host: the poll passes no blocking device read, and
+    its rows equal a numpy-backend replica's after the same poll."""
+    from repro.kernels.device_io import DEVICE_IO
+    groups = {"w": 1, "v": 8}
+    plan = RoutingPlan(1, 1, 2)
+    opt = get_optimizer("ftrl")
+    queue = PartitionedQueue(2)
+    master = MasterShard(0, groups, opt)
+    col = Collector()
+    master.collector = col
+    pusher = Pusher(master, queue, plan,
+                    make_transform("int8", opt, backend="pallas"))
+    rng = np.random.default_rng(4)
+    for step in range(2):
+        for g, dim in groups.items():
+            ids = rng.integers(0, 300, size=96).astype(np.int64)
+            master.push_grad(
+                g, ids, rng.normal(size=(96, dim)).astype(np.float32))
+        gatherer = Gatherer("realtime")
+        gatherer.offer(col.drain())
+        pusher.push(gatherer.flush(step), now=float(step))
+    replicas = {b: SlaveShard(0, groups, backend="pallas", codec_backend=b)
+                for b in ("pallas", "numpy")}
+    scatters = {b: Scatter(s, queue, plan) for b, s in replicas.items()}
+    waits = DEVICE_IO.waits
+    applied = scatters["pallas"].poll()
+    assert DEVICE_IO.waits == waits
+    assert applied == scatters["numpy"].poll() > 0
+    recs = [r for p in range(2) for r in queue.consume(p, 0)[0]]
+    assert {r.meta["codec"] for r in recs} == {"int8"}
+    assert {r.group for r in recs} == set(groups)
+    for g in groups:
+        ids = np.sort(master.tables[g].all_ids())
+        np.testing.assert_array_equal(replicas["pallas"].lookup(g, ids),
+                                      replicas["numpy"].lookup(g, ids))
+
+
 def test_batched_scatter_lww_within_poll():
     """Overlapping ids across records inside ONE poll resolve
     last-writer-wins by arrival order — identical to sequential apply —

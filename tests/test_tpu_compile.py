@@ -43,6 +43,10 @@ def one_chip():
 @pytest.fixture
 def mosaic(monkeypatch):
     monkeypatch.setattr(ops, "_interpret", lambda: False)
+    yield
+    # jit reuses a trace for the same shapes whatever the sharding, so a
+    # later CPU call at these shapes would get the Mosaic trace
+    jax.clear_caches()
 
 
 def _spec(sharding, shape, dtype):
