@@ -4,10 +4,14 @@ and fill the system, warm up, measure the window (traced with
 the result line.
 
 A cell ``<name>`` of ``BENCHMARK.json`` names a configuration and a
-traffic mix; the harness reads ``configs/<config>.json``,
+traffic mix; the harness reads the configuration's file,
 ``traffic/<traffic>.json`` and ``cells/<name>.json`` (the cell's own
 numbers: its offered rate, its limits), and every metric's reader
-``metrics/<metric>.py``, all under this directory.
+``metrics/<metric>.py``, all under this directory. The configuration's
+``family`` names ``families/<family>.py``, the module that knows its
+model: it builds and fills the system, makes its traffic, reads back
+what the program holds and gives the numbers compared with the plain
+reference.
 """
 
 from __future__ import annotations
@@ -25,8 +29,7 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from harness import check, counts, generate as gen, peaks as peaks_mod
-from harness import reference as ref_mod
+from harness import check, counts, peaks as peaks_mod
 
 HERE = Path(__file__).resolve().parents[1]          # perfbench/
 ROOT = HERE.parent
@@ -53,7 +56,25 @@ def load_spec(workload: str) -> dict:
     traffic = load_json(HERE / "traffic" / f"{w['traffic']}.json")
     cell = load_json(HERE / "cells" / f"{workload}.json")
     return {"bench": bench, "workload": w, "cfg": cfg, "traffic": traffic,
-            "cell": cell}
+            "cell": cell, "family": load_family(cfg.get("family"))}
+
+
+def load_family(name: str):
+    """The module ``families/<name>.py``: a configuration's ``family``.
+    It gives ``build``, ``preseed``, ``train_stream``, ``requests``,
+    ``collect``, ``judge``, ``readings`` and ``tiny``; ``families/
+    ctr_ftrl.py`` shows what each takes and returns."""
+    path = HERE / "families" / f"{name}.py"
+    if not name or not path.is_file():
+        raise Refused(f"no family module families/{name}.py")
+    return _load(path, "perfbench_family_" + name)
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metrics_for(spec: dict, trace: bool) -> list:
@@ -70,12 +91,8 @@ def metrics_for(spec: dict, trace: bool) -> list:
 
 
 def reader(metric: str):
-    path = HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(HERE / "metrics" / f"{metric}.py",
+                 "perfbench_metric_" + metric.replace(".", "_")).read
 
 
 def device_info(chips: int, allow_cpu: bool = False) -> dict:
@@ -98,6 +115,7 @@ def execute(spec: dict, seed: int, seconds: float, trace: bool, *,
     metrics need and free the program's state."""
     import jax
     cfg, traffic, cell = spec["cfg"], spec["traffic"], spec["cell"]
+    fam = spec["family"]
     dev = device_info(int(spec["workload"]["chips"]), allow_cpu)
     try:
         pk = peaks_mod.for_kind(dev["kind"])
@@ -109,19 +127,18 @@ def execute(spec: dict, seed: int, seconds: float, trace: bool, *,
 
     t0 = time.perf_counter()
     compiles = drive.CompileCounter()
-    vocab = gen.Vocab(cfg["field_vocab"])
-    cl = drive.make_cluster(cfg, seed)
+    cl = fam.build(cfg, seed)
     kind = traffic["kind"]
     train = kind == "train_stream"
-    loaded = drive.preseed(cl, cfg, vocab, seed, masters=train,
-                           replicas=True)
+    loaded = fam.preseed(cl, cfg, seed, masters=train, replicas=True)
     t_seed = time.perf_counter() - t0
     spans = drive.Spans(annotate=trace)
     if train:
-        drv = drive.TrainDriver(cl, cfg, traffic, vocab, seed, spans)
+        drv = drive.TrainDriver(cl, fam.train_stream(cfg, traffic, seed),
+                                traffic, spans)
     elif kind == "open_loop_predict":
-        drv = drive.ServeDriver(cl, cfg, traffic, vocab, seed, spans,
-                                float(cell["rate_per_s"]))
+        drv = drive.ServeDriver(cl, fam.requests(cfg, traffic), traffic,
+                                seed, spans, float(cell["rate_per_s"]))
     else:
         raise Refused(f"unknown traffic kind {kind!r}")
     drv.warm()
@@ -165,12 +182,12 @@ def execute(spec: dict, seed: int, seconds: float, trace: bool, *,
                          batches=None, events=None, stream_from=0,
                          sample=None, unique_per_batch=None)
     if train:
-        st.out = drv.collect()
+        st.out = fam.collect(cl, cfg, drv.batches)
         st.batches = drv.batches
         st.events = drv.events
         st.stream_from = drv.stream_from
         st.unique_per_batch = [
-            {g: len(np.unique(b[0])) for g in cfg["groups"]}
+            drv.stream.unique_per_batch(b)
             for b in drv.batches[len(drv.batches) - drv.window_batches:]]
     else:
         st.sample = drv.sample
@@ -181,12 +198,8 @@ def execute(spec: dict, seed: int, seconds: float, trace: bool, *,
 
 
 def judge(spec: dict, seed: int, st: SimpleNamespace) -> dict:
-    """The numbers compared with the float32 reference."""
-    if st.train:
-        ref = ref_mod.TrainReference(spec["cfg"], seed)
-        ref.replay(st.batches)
-        return check.train_judged(spec, st, ref)
-    return check.serve_numbers(spec["cfg"], seed, st.sample)
+    """The numbers compared with the plain reference (the family's)."""
+    return spec["family"].judge(spec, seed, st)
 
 
 def run(spec: dict, seed: int, seconds: float, trace: bool, *,

@@ -1,7 +1,8 @@
-"""The comparison that decides ``correct``: the numbers compared with the
-plain reference, each against the limit the cell's file sets.
+"""The comparison that decides ``correct``. ``verdict`` holds a run's
+numbers to the limits its cell's file sets, for every family; the rest
+is family ``ctr_ftrl``'s numbers, compared with its plain reference.
 
-Train cells compare, for every id the recorded batches touched:
+Its train cells compare, for every id the recorded batches touched:
 
 - ``rows_err``: the master rows (FTRL z, n, w) after the window, the
   largest gap to the reference in any group and column, over the largest
@@ -18,7 +19,7 @@ Train cells compare, for every id the recorded batches touched:
 The reference replays the program's train batches in the program's
 order; the join numbers hold their rows to the generated stream.
 
-Serve cells compare ``pred_err``: the largest gap between a returned
+Its serve cells compare ``pred_err``: the largest gap between a returned
 prediction and the reference's, over a seeded sample of the window's
 requests that holds the longest one.
 """
@@ -156,12 +157,24 @@ def verdict(numbers: dict, limits: dict) -> tuple[bool, dict]:
     return ok, shown
 
 
+def train_rows(records: list) -> list:
+    """The ``train_batch`` calls recorded at the training plane's entry
+    (each a dict of the call's arguments) as (ids, labels, weights)."""
+    rows = []
+    for a in records:
+        ids = np.asarray(a["ids"], np.int64)
+        w = a["weights"]
+        rows.append((ids, np.asarray(a["y"], np.float32),
+                     np.ones(len(ids), np.float32) if w is None else
+                     np.asarray(w, np.float32)))
+    return rows
+
+
 def train_judged(spec: dict, st, ref: "ref_mod.TrainReference",
-                 out: dict = None, batches: list = None) -> dict:
+                 batches: list, out: dict = None) -> dict:
     """A train run's numbers: its rows (or ``out``, a stand-in's) against
-    ``ref``, and its join's train batches (or ``batches``) against the
-    generated stream."""
-    batches = st.batches if batches is None else batches
+    ``ref``, and the join's part of ``batches`` (``train_rows``) against
+    the generated stream."""
     return {**train_numbers(st.out if out is None else out, ref),
             **join_numbers(st.events, batches[st.stream_from:],
                            spec["cfg"]["cluster"]["join_window_s"],
@@ -181,22 +194,22 @@ def train_readings(spec: dict, seed: int, st) -> dict:
     reference's place: a step that leaves the state unchanged, and half
     of every batch left out."""
     cfg = spec["cfg"]
+    rows = train_rows(st.batches)
     ref = ref_mod.TrainReference(cfg, seed)
-    ref.replay(st.batches)
+    ref.replay(rows)
     ids = st.out["ids"]
     ctrl = ref_mod.TrainReference(cfg, seed, "bfloat16")
-    ctrl.replay(st.batches)
-    halved = half_batches(st.batches)
+    ctrl.replay(rows)
+    halved = half_batches(rows)
     half = ref_mod.TrainReference(cfg, seed)
     half.replay(halved)
-    return {"program": train_judged(spec, st, ref),
-            "control": train_judged(spec, st, ref,
+    return {"program": train_judged(spec, st, ref, rows),
+            "control": train_judged(spec, st, ref, rows,
                                     reference_as_output(ctrl, ids)),
-            "unchanged": train_judged(spec, st, ref,
+            "unchanged": train_judged(spec, st, ref, rows,
                                       unchanged_output(ref, ids)),
-            "half_batch": train_judged(spec, st, ref,
-                                       reference_as_output(half, ids),
-                                       halved)}
+            "half_batch": train_judged(spec, st, ref, halved,
+                                       reference_as_output(half, ids))}
 
 
 def serve_readings(cfg: dict, seed: int, sample: list) -> dict:

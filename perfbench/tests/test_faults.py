@@ -29,7 +29,7 @@ def test_train_control_and_faults_read_over_a_limit(workload):
     seed = 2 ** 32 + 3
     st = bench.execute(spec, seed, 1.5, False, allow_cpu=True,
                        log=lambda *a, **k: None)
-    r = check.train_readings(spec, seed, st)
+    r = spec["family"].readings(spec, seed, st)
     lim = _limits(workload)
     assert not _over(r["program"], lim), r
     for kind in ("control", "unchanged", "half_batch"):
@@ -41,7 +41,7 @@ def test_serve_control_reads_over_the_limit():
     seed = 2 ** 32 + 5
     st = bench.execute(spec, seed, 1.5, False, allow_cpu=True,
                        log=lambda *a, **k: None)
-    r = check.serve_readings(spec["cfg"], seed, st.sample)
+    r = spec["family"].readings(spec, seed, st)
     lim = _limits("fm_ftrl.serve_zipf")
     assert not _over(r["program"], lim), r
     assert _over(r["control"], lim), r
@@ -118,6 +118,6 @@ def test_answer_altered_where_produced_is_not_correct(monkeypatch):
     spec = tiny.spec("fm_ftrl.serve_zipf")
     seed = 2 ** 33 + 17
     st = tiny.execute("fm_ftrl.serve_zipf", seed=seed)
-    ok, _ = check.verdict(check.serve_numbers(spec["cfg"], seed, st.sample),
+    ok, _ = check.verdict(bench.judge(spec, seed, st),
                           spec["cell"]["limits"])
     assert not ok

@@ -59,8 +59,7 @@ def test_open_loop_serve():
     assert (s["latency_s"] > 0).all()
     # ids over whole vocabularies: the window's requests miss the cache
     assert 0 < s["cache"]["hit_rate"] < 1
-    ok, shown = check.verdict(check.serve_numbers(spec["cfg"], seed,
-                                                  st.sample),
+    ok, shown = check.verdict(bench.judge(spec, seed, st),
                               spec["cell"]["limits"])
     assert ok, shown
 

@@ -1,10 +1,7 @@
 """Each cell cut to a size the CPU runs in seconds (Pallas in interpret
-mode): a few hundred ids a field, a few hundred events a tick, short
-requests. Widths, fields' count, layout and codec stay as configured."""
+mode), as its configuration's family cuts it (the family's ``tiny``)."""
 
 from harness import bench
-
-VOCAB = [64, 3, 500, 2000, 37, 900]
 
 # cells whose files are here but which BENCHMARK.json does not list
 # (PERF.md, Open questions): cell -> (its configuration, its traffic)
@@ -24,24 +21,19 @@ def load(workload: str) -> dict:
     for m in b["end_to_end"] + b["per_layer"]:
         if like in m.get("workloads", ()):
             m["workloads"] = m["workloads"] + [workload]
+    cfg = bench.load_json(h / "configs" / f"{config}.json")
     return {"bench": b,
             "workload": {"name": workload, "config": config,
                          "traffic": traffic, "chips": 1},
-            "cfg": bench.load_json(h / "configs" / f"{config}.json"),
+            "cfg": cfg,
             "traffic": bench.load_json(h / "traffic" / f"{traffic}.json"),
-            "cell": bench.load_json(h / "cells" / f"{workload}.json")}
+            "cell": bench.load_json(h / "cells" / f"{workload}.json"),
+            "family": bench.load_family(cfg["family"])}
 
 
 def spec(workload: str) -> dict:
     s = load(workload)
-    s["cfg"]["field_vocab"] = list(VOCAB)
-    s["cfg"]["sizing"]["ids_per_master"] = sum(VOCAB) // 4
-    t = s["traffic"]
-    if t["kind"] == "train_stream":
-        t.update(events_per_tick=256, warm_ticks=2)
-    else:
-        t.update(max_examples=64, warm_requests=8, check_requests=8)
-        s["cell"]["rate_per_s"] = 20.0
+    s["family"].tiny(s)
     return s
 
 

@@ -1,9 +1,9 @@
 """The readings a cell's limits are set from: on each seed, one run of
-the cell at its own size (a short window), then the program's numbers
-against the float32 reference, the control's (the reference in
-bfloat16 in the program's place) and, for train cells, the planted
-faults' (state left unchanged; half of each batch left out). One process
-for all seeds, so set-up compiles once.
+the cell at its own size (a short window), then its family's readings
+(``ctr_ftrl``: the program's numbers against the float32 reference, the
+control's, the reference in bfloat16 in the program's place, and, for
+train cells, the planted faults': state left unchanged, half of each
+batch left out). One process for all seeds, so set-up compiles once.
 
     python3 perfbench/tools/readings.py --workload <cell> --seconds 5 \\
         --seeds 11 12 13 [--out readings.jsonl]
@@ -28,7 +28,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--out")
     args = ap.parse_args(argv)
-    from harness import bench, check
+    from harness import bench
     spec = bench.load_spec(args.workload)
     bench.device_info(int(spec["workload"]["chips"]))
     sys.path.insert(0, str(bench.ROOT / "src"))
@@ -37,11 +37,8 @@ def main(argv=None) -> int:
     rows = []
     for seed in args.seeds:
         st = bench.execute(spec, seed, args.seconds, False)
-        if st.train:
-            r = check.train_readings(spec, seed, st)
-        else:
-            r = check.serve_readings(spec["cfg"], seed, st.sample)
-        r = {"seed": seed, "setup_s": st.setup_s, **r}
+        r = {"seed": seed, "setup_s": st.setup_s,
+             **spec["family"].readings(spec, seed, st)}
         rows.append(r)
         print(json.dumps(r), flush=True)
         if args.out:

@@ -18,6 +18,7 @@ import json
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -35,7 +36,6 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=2 ** 31 + 11)
     args = ap.parse_args(argv)
     from harness import bench, check, drive
-    from harness import generate as gen
     bench.device_info(1)
     sys.path.insert(0, str(bench.ROOT / "src"))
     from repro.compile_cache import enable_compile_cache
@@ -43,13 +43,13 @@ def main(argv=None) -> int:
     cfg = bench.load_json(HERE / "configs" / f"{args.config}.json")
     traffic = bench.load_json(HERE / "traffic" / f"{args.traffic}.json")
     cell = bench.load_json(HERE / "cells" / f"{args.cell}.json")
+    fam = bench.load_family(cfg["family"])
     t0 = time.perf_counter()
     compiles = drive.CompileCounter()
-    vocab = gen.Vocab(cfg["field_vocab"])
-    cl = drive.make_cluster(cfg, args.seed)
-    drive.preseed(cl, cfg, vocab, args.seed, masters=False, replicas=True)
-    drv = drive.ServeDriver(cl, cfg, traffic, vocab, args.seed,
-                            drive.Spans(), args.rates[0])
+    cl = fam.build(cfg, args.seed)
+    fam.preseed(cl, cfg, args.seed, masters=False, replicas=True)
+    drv = drive.ServeDriver(cl, fam.requests(cfg, traffic), traffic,
+                            args.seed, drive.Spans(), args.rates[0])
     drv.warm()
     print(json.dumps({"setup_s": time.perf_counter() - t0,
                       "setup_compiles": compiles.count}), flush=True)
@@ -61,8 +61,9 @@ def main(argv=None) -> int:
         st = drv.window(args.seconds)
         lat = st["latency_s"] * 1e3
         k = max(1, len(lat) // 5)
-        ok, shown = check.verdict(
-            check.serve_numbers(cfg, args.seed, drv.sample), cell["limits"])
+        served = SimpleNamespace(train=False, sample=drv.sample)
+        ok, shown = check.verdict(fam.judge({"cfg": cfg}, args.seed, served),
+                                  cell["limits"])
         row = {"rate": rate, "requests": len(lat),
                "compiles": compiles.count - c0,
                "p50_ms": float(np.percentile(lat, 50)),
