@@ -31,6 +31,7 @@ from repro.data.joiner import SampleJoiner
 from repro.models import ctr as ctr_model
 from repro.optim import get_optimizer
 from repro.serving import RowRouter, ServingPlane
+from repro.serving.plane import needs_dense_features
 from repro.serving.scheduler import DEFAULT_BUCKETS
 from repro.training.pipeline import TRAIN_BUCKETS, TrainPipeline
 from repro.training.plane import TrainingPlane
@@ -159,7 +160,10 @@ class WeiPSCluster:
             max_replica_lag=c.serve_max_lag,
             cache_rows=c.serve_cache_rows, buckets=c.serve_buckets,
             ps_backend=c.ps_backend, admission=admission, clock=clock)
-        self.add_scenario(model_cfg)          # default scenario
+        # default scenario; a model that needs dense features has none
+        # (the serve path carries ids only: ServingPlane.add_scenario)
+        if not needs_dense_features(model_cfg):
+            self.add_scenario(model_cfg)
         for rs in self.replica_sets:
             for shard in rs.replicas:
                 shard.on_apply = self.serving.on_applied
@@ -171,7 +175,8 @@ class WeiPSCluster:
         self.training = TrainingPlane(
             self.plan, self.masters, self.groups, self.optimizer,
             feature_filter=self.filter,
-            on_new_groups=self._on_new_train_groups, seed=c.seed)
+            on_new_groups=self._on_new_train_groups, seed=c.seed,
+            max_batch=max(c.train_buckets))
         self.train_scheduler = TrainScheduler(self.training)
         default_scn = self.training.add_scenario(model_cfg)
         self.scheduler.register_train_scenario(
@@ -406,11 +411,11 @@ class WeiPSCluster:
     @staticmethod
     def _apply_dense_state(shard: SlaveShard, dense: dict) -> None:
         """Install a materialized dense bank on a serving replica (the
-        slave holds flattened decoded tensors + version counters, so
+        slave holds decoded tensors in their shapes + version counters, so
         replayed dense records older than the restored version LWW-skip
         and newer ones apply)."""
         for name, t in dense["tensors"].items():
-            shard.dense[name] = np.asarray(t, np.float32).reshape(1, -1)
+            shard.dense[name] = np.asarray(t, np.float32)
             shard.dense_versions[name] = dense["versions"][name]
 
     def _hot_switch(self, ckpt: Checkpoint) -> None:

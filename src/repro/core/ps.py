@@ -870,8 +870,9 @@ class SlaveShard:
             name = record.group[len("dense/"):]
             ver = int(record.ids[0])
             if self.dense_versions.get(name, -1) < ver:
-                self.dense[name] = decode_record(record,
-                                                 backend=self.codec_backend)
+                self.dense[name] = decode_record(
+                    record, backend=self.codec_backend).reshape(
+                    record.meta.get("shape", (1, -1)))
                 self.dense_versions[name] = ver
         elif record.op == "delete":
             self.tables[record.group].evict(record.ids)

@@ -214,12 +214,12 @@ class Pusher:
         if value is None:
             return 0
         ver = self.shard.dense.versions[name]
-        # copy: identity encode passes arrays through uncopied, and a
-        # queued payload must never alias the live dense tensor
+        # each row its own int8 scale: a matrix by its rows, a vector as
+        # one row (the replica restores the shape from ``meta``)
+        rows = value.reshape(-1, value.shape[-1]) if value.ndim > 1 \
+            else value.reshape(1, -1)
         with obs_trace.get_tracer().span("sync.encode"):
-            payload = self.transform.encode(
-                value.reshape(1, -1).copy(),
-                self.shard.dense.slots.get(name, {}))
+            payload = self.transform.encode_values(rows)
         meta = {"codec": self.transform.name, "t": now,
                 "shape": value.shape}
         if self._tmeta is not None:

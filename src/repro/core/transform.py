@@ -143,6 +143,13 @@ class Transform:
             w, slots,
             lambda v: {"values": v.astype(np.float32, copy=False)})
 
+    def encode_values(self, v: np.ndarray) -> dict:
+        """The codec alone over (rows, D) values that are already serve
+        weights — a dense tensor's rows: no serve derivation from slots.
+        The payload never aliases ``v`` (a queued payload must not alias
+        a live tensor)."""
+        return {"values": np.array(v, np.float32)}
+
     @staticmethod
     def decode(payload: dict, backend: str = "numpy") -> np.ndarray:
         return payload["values"]
@@ -157,6 +164,9 @@ class Cast16Transform(Transform):
     def encode(self, w, slots):
         return self._assemble(
             w, slots, lambda v: {"values16": v.astype(np.float16)})
+
+    def encode_values(self, v):
+        return {"values16": v.astype(np.float16)}
 
     @staticmethod
     def decode(payload, backend: str = "numpy"):
@@ -190,6 +200,15 @@ class Int8Transform(Transform):
             q, scale = ops.quantize_rows(self.serve_values(w, slots))
             return {"q": q, "scale": scale}
         return self._assemble(w, slots, self._quantize_np)
+
+    def encode_values(self, v):
+        # a dense tensor has few rows: pad them to a small floor, not to
+        # a sparse flush's
+        if self._device_path and len(v):
+            from repro.kernels import ops
+            q, scale = ops.quantize_rows(v, min_rows=8)
+            return {"q": q, "scale": scale}
+        return self._quantize_np(v)
 
     @staticmethod
     def decode(payload, backend: str = "numpy"):

@@ -83,6 +83,8 @@ class JoinedBatch:
     labels: np.ndarray         # (n,) float32
     join_delay: np.ndarray     # (n,) float32
     weights: np.ndarray        # (n,) float32 downsampling correction
+    dense: Optional[np.ndarray] = None   # (n, D) float32 dense features,
+    #                                      where the exposures carried them
 
     def __len__(self) -> int:
         return len(self.view_ids)
@@ -103,17 +105,20 @@ class JoinedBatch:
         return JoinedBatch(
             t_emit=self.t_emit[s], view_ids=self.view_ids[s],
             feature_ids=self.feature_ids[s], labels=self.labels[s],
-            join_delay=self.join_delay[s], weights=self.weights[s])
+            join_delay=self.join_delay[s], weights=self.weights[s],
+            dense=None if self.dense is None else self.dense[s])
 
     @staticmethod
-    def empty(fields: int) -> "JoinedBatch":
+    def empty(fields: int, dense: Optional[int] = None) -> "JoinedBatch":
         z = np.empty(0, np.float64)
         return JoinedBatch(
             t_emit=z, view_ids=np.empty(0, np.int64),
             feature_ids=np.empty((0, fields), np.int64),
             labels=np.empty(0, np.float32),
             join_delay=np.empty(0, np.float32),
-            weights=np.empty(0, np.float32))
+            weights=np.empty(0, np.float32),
+            dense=None if dense is None else np.empty((0, dense),
+                                                      np.float32))
 
     @staticmethod
     def concat(batches: list["JoinedBatch"]) -> "JoinedBatch":
@@ -125,7 +130,9 @@ class JoinedBatch:
             feature_ids=np.concatenate([b.feature_ids for b in batches]),
             labels=np.concatenate([b.labels for b in batches]),
             join_delay=np.concatenate([b.join_delay for b in batches]),
-            weights=np.concatenate([b.weights for b in batches]))
+            weights=np.concatenate([b.weights for b in batches]),
+            dense=None if batches[0].dense is None else
+            np.concatenate([b.dense for b in batches]))
 
 
 _DELAY_RING = 1 << 14      # recent join delays kept for percentile metrics
@@ -157,6 +164,7 @@ class SampleJoiner:
         self._t = np.empty(cap, np.float64)
         self._label = np.zeros(cap, np.float32)
         self._feat: Optional[np.ndarray] = None     # (cap, F), F from 1st offer
+        self._dense: Optional[np.ndarray] = None    # (cap, D) where offered
         self._live = np.zeros(cap, bool)
         self._rows = 0                 # high-water mark of the row arena
         self._dead = 0                 # rows freed by emit (compaction debt)
@@ -177,10 +185,13 @@ class SampleJoiner:
     # ------------------------------------------------------------------
     # storage
     # ------------------------------------------------------------------
-    def _grow_rows(self, need: int, fields: int) -> None:
+    def _grow_rows(self, need: int, fields: int,
+                   dense: Optional[int] = None) -> None:
         cap = len(self._vid)
         if self._feat is None:
             self._feat = np.empty((cap, fields), np.int64)
+            if dense is not None:
+                self._dense = np.empty((cap, dense), np.float32)
         if need <= cap:
             return
         new_cap = max(need, cap * 2)
@@ -193,6 +204,8 @@ class SampleJoiner:
         self._vid = grow(self._vid)
         self._t = grow(self._t)
         self._feat = grow(self._feat)
+        if self._dense is not None:
+            self._dense = grow(self._dense)
         lbl = np.zeros(new_cap, np.float32)
         lbl[:cap] = self._label
         self._label = lbl
@@ -208,6 +221,8 @@ class SampleJoiner:
         self._vid[:n] = self._vid[keep]
         self._t[:n] = self._t[keep]
         self._feat[:n] = self._feat[keep]
+        if self._dense is not None:
+            self._dense[:n] = self._dense[keep]
         self._label[:n] = self._label[keep]
         self._live[:n] = True
         self._live[n:self._rows] = False
@@ -234,11 +249,14 @@ class SampleJoiner:
     # batch API (the hot path)
     # ------------------------------------------------------------------
     def offer_exposures(self, t, view_ids: np.ndarray,
-                        feature_ids: np.ndarray) -> None:
+                        feature_ids: np.ndarray,
+                        dense: Optional[np.ndarray] = None) -> None:
         """Offer a batch of exposures at time(s) ``t`` (scalar or (n,)).
         Later occurrences of a duplicate view_id (within the batch or
         across offers) overwrite the pending features/time — the seed's
-        dict semantics — while every offer's expiry entry stays live."""
+        dict semantics — while every offer's expiry entry stays live.
+        ``dense`` (n, D) float features ride with the exposure, unchanged,
+        to the joined row; a stream offers them always or never."""
         view_ids = np.asarray(view_ids, np.int64)
         feature_ids = np.asarray(feature_ids, np.int64)
         n = len(view_ids)
@@ -248,6 +266,11 @@ class SampleJoiner:
         if self._feat is not None and feature_ids.shape[1] != \
                 self._feat.shape[1]:
             raise ValueError("feature_ids width changed mid-stream")
+        if dense is not None:
+            dense = np.asarray(dense, np.float32)
+        if self._feat is not None and self._dense_width != (
+                None if dense is None else dense.shape[1]):
+            raise ValueError("dense features changed mid-stream")
         self._append_entries(ts + self.window, view_ids)
 
         # strictly monotonic vids (the streaming common case: view ids
@@ -267,6 +290,8 @@ class SampleJoiner:
             last[n - 1 - first_of_last] = True
             view_ids, ts = view_ids[last], ts[last]
             feature_ids = feature_ids[last]
+            if dense is not None:
+                dense = dense[last]
             n = len(view_ids)
 
         sl, have = self._map.lookup_mask(view_ids)
@@ -274,17 +299,22 @@ class SampleJoiner:
             rows = sl[have]
             self._t[rows] = ts[have]
             self._feat[rows] = feature_ids[have]
+            if dense is not None:
+                self._dense[rows] = dense[have]
             # label survives a re-offer of a LIVE row (seed keeps its
             # labels dict untouched on duplicate offer_exposure)
         miss = ~have
         k = int(miss.sum())
         if k:
-            self._grow_rows(self._rows + k, feature_ids.shape[1])
+            self._grow_rows(self._rows + k, feature_ids.shape[1],
+                            None if dense is None else dense.shape[1])
             rows = np.arange(self._rows, self._rows + k)
             self._rows += k
             self._vid[rows] = view_ids[miss]
             self._t[rows] = ts[miss]
             self._feat[rows] = feature_ids[miss]
+            if dense is not None:
+                self._dense[rows] = dense[miss]
             self._label[rows] = 0.0
             self._live[rows] = True
             # absent-by-probe above: skip put()'s second existence probe
@@ -350,7 +380,7 @@ class SampleJoiner:
         downsampler."""
         ne = self._ne
         if ne == 0 or not (self._ed[:ne] <= now).any():
-            return JoinedBatch.empty(self._fields)
+            return JoinedBatch.empty(self._fields, self._dense_width)
         expired = self._ed[:ne] <= now
         exp_d, exp_v = self._ed[:ne][expired], self._ev[:ne][expired]
         keep = ~expired
@@ -367,7 +397,7 @@ class SampleJoiner:
         sl = self._map.lookup(uniq_v)
         live = sl >= 0
         if not live.any():
-            return JoinedBatch.empty(self._fields)
+            return JoinedBatch.empty(self._fields, self._dense_width)
         # emission order across vids = order of their first expired entry
         emit_order = np.argsort(first[live], kind="stable")
         rows = sl[live][emit_order]
@@ -403,7 +433,8 @@ class SampleJoiner:
             feature_ids=self._feat[rows].copy(),
             labels=np.asarray(labels, np.float32),
             join_delay=delay,
-            weights=np.asarray(weights, np.float32))
+            weights=np.asarray(weights, np.float32),
+            dense=None if self._dense is None else self._dense[rows].copy())
         self.emitted += len(rows)
         self._record_delays(delay)
         self._release_rows(rows if release is None else release)
@@ -443,6 +474,10 @@ class SampleJoiner:
     @property
     def _fields(self) -> int:
         return self._feat.shape[1] if self._feat is not None else 0
+
+    @property
+    def _dense_width(self) -> Optional[int]:
+        return self._dense.shape[1] if self._dense is not None else None
 
     @property
     def in_flight(self) -> int:

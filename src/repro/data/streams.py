@@ -29,6 +29,8 @@ class EventBatch:
     labels: np.ndarray         # (n,) ground-truth labels (for evaluation)
     fb_view_ids: np.ndarray    # (k,) positives' view ids
     fb_t: np.ndarray           # (k,) feedback arrival times
+    dense: Optional[np.ndarray] = None   # (n, D) float32 dense features,
+    #                                      for models that read them
 
     def __len__(self) -> int:
         return len(self.view_ids)

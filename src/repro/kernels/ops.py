@@ -15,9 +15,12 @@ The PS entry points (``embedding_scatter``, ``fused_lookup``,
 ``fused_gather``, ``fused_ftrl_apply``, ``quantize_rows``,
 ``dequantize_rows``) take host arrays of any length, pad them to a few
 power-of-two lengths — every distinct length compiles its own program —
-and return unpadded results. What they hand to the device and read back
-is counted, and each blocking read is a span, through
-``kernels/device_io.py``.
+and return unpadded results. ``PooledLookup`` (multi-hot fields
+sum-pooled from a batch's unique rows, and its transpose) moves rows in
+fixed-size chunks through one device buffer instead, so its programs
+compile once per batch shape whatever the unique-row count. What they
+hand to the device and read back is counted, and each blocking read is a
+span, through ``kernels/device_io.py``.
 """
 
 from __future__ import annotations
@@ -51,10 +54,11 @@ def int64_limbs(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return np.ascontiguousarray(v[:, 0]), np.ascontiguousarray(v[:, 1])
 
 
-def _bucket(n: int) -> int:
+def _bucket(n: int, floor: int = _hm.OFFSET_BLOCK) -> int:
     """Padded length of an ``n``-row device call: the next power of two, at
-    least the probe's batch granule (``hashmap_probe.OFFSET_BLOCK``)."""
-    return max(_hm.OFFSET_BLOCK, 1 << (max(n, 1) - 1).bit_length())
+    least ``floor`` (the probe's batch granule,
+    ``hashmap_probe.OFFSET_BLOCK``, unless the caller gives another)."""
+    return max(floor, 1 << (max(n, 1) - 1).bit_length())
 
 
 def _pad(a, n: int, fill) -> np.ndarray:
@@ -225,11 +229,14 @@ def _dequantize_program(q, scale):
     return _dc.dequantize_rows(q, scale, interpret=_interpret())
 
 
-def quantize_rows(x):
+def quantize_rows(x, *, min_rows: int = _hm.OFFSET_BLOCK):
     """Row-wise absmax int8 of an (N, D) f32 array of any length: host
-    (q (N, D) int8, scale (N, 1) f32)."""
+    (q (N, D) int8, scale (N, 1) f32). Rows are padded to a power of two,
+    at least ``min_rows`` (a dense tensor's few rows take a smaller
+    floor than a sparse flush's)."""
     x = np.asarray(x, np.float32)
-    xp = _pad(x, _bucket(len(x)), 0)
+    nb = _bucket(len(x), min_rows)
+    xp = x if len(x) == nb else _pad(x, nb, 0)
     count_h2d(xp)
     q, scale = to_host(*_quantize_program(xp))
     return q[:len(x)], scale[:len(x)]
@@ -242,6 +249,103 @@ def dequantize_rows(q, scale):
     ins = (_pad(q, nb, 0), _pad(scale, nb, 1))
     count_h2d(*ins)
     return to_host(_dequantize_program(*ins))[0][:len(q)]
+
+
+POOL_CHUNK = 16384       # rows a PooledLookup upload or read moves at once
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_rows(buf, rows, start):
+    return jax.lax.dynamic_update_slice(buf, rows, (start, 0))
+
+
+@jax.jit
+def _get_rows(buf, start):
+    return jax.lax.dynamic_slice(buf, (start, 0), (POOL_CHUNK, buf.shape[1]))
+
+
+@functools.partial(jax.jit, static_argnames=("sizes",))
+def _pooled_lookup(rows, inv, *, sizes):
+    """(B, len(sizes), D): row ``inv[b, s]`` of ``rows`` summed over each
+    field's static slot range (``sizes`` slots a field, in order)."""
+    g = jnp.take(rows, inv, axis=0)
+    out, lo = [], 0
+    for n in sizes:
+        out.append(g[:, lo:lo + n].sum(axis=1))
+        lo += n
+    return jnp.stack(out, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _pooled_grad(g, src, dst, *, rows):
+    """The transpose of ``_pooled_lookup``: slot ``k``'s gradient (its
+    field's pooled gradient, row ``src[k]`` of ``g`` flattened) summed
+    into unique row ``dst[k]``; ``dst`` ascending, so the scatter-add is
+    a sorted segment sum."""
+    part = jnp.take(g.reshape(-1, g.shape[-1]), src, axis=0)
+    return jnp.zeros((rows, g.shape[-1]), g.dtype).at[dst].add(
+        part, indices_are_sorted=True)
+
+
+class PooledLookup:
+    """Sum-pooled multi-hot lookup on the device, and its transpose.
+
+    A batch's unique rows ``(U, D)`` go up once, in ``POOL_CHUNK``-row
+    pieces, into a device buffer of ``max_rows`` rows (rounded up to whole
+    chunks); with the ``(B, S)`` inverse (slot ``s`` of example ``b``
+    reads unique row ``inv[b, s]``), ``lookup`` gathers and sums each
+    field's static slot range, ``(B, F, D)``, and ``grad`` takes a
+    ``(B, F, D)`` gradient back to ``(U, D)`` unique-row gradients,
+    reduced on the device and read back in chunks. Neither the ``(B, S,
+    D)`` rows nor their gradients leave the device; the programs compile
+    once per ``(B, S)`` shape. A batch with more unique rows than the
+    buffer holds grows it (and compiles again)."""
+
+    def __init__(self, sizes, dim: int, max_rows: int):
+        self.sizes = tuple(int(n) for n in sizes)
+        self.dim = int(dim)
+        self.field_of = np.repeat(np.arange(len(self.sizes), dtype=np.int32),
+                                  self.sizes)
+        self.cap = 0
+        self._grow(max_rows)
+
+    def _grow(self, rows: int) -> None:
+        cap = -(-max(int(rows), 1) // POOL_CHUNK) * POOL_CHUNK
+        if cap > self.cap:
+            self.cap = cap
+            self._buf = jnp.zeros((cap, self.dim), jnp.float32)
+
+    def lookup(self, rows: np.ndarray, inv: np.ndarray):
+        """Pooled ``(B, F, D)`` device rows of host unique ``rows`` and the
+        host int32 inverse ``inv`` ``(B, S)``."""
+        rows = np.asarray(rows, np.float32)
+        self._grow(len(rows))
+        for lo in range(0, len(rows), POOL_CHUNK):
+            part = rows[lo:lo + POOL_CHUNK]
+            if len(part) < POOL_CHUNK:
+                part = _pad(part, POOL_CHUNK, 0)
+            count_h2d(part)
+            self._buf = _put_rows(self._buf, part, np.int32(lo))
+        inv = np.asarray(inv, np.int32)
+        count_h2d(inv)
+        return _pooled_lookup(self._buf, inv, sizes=self.sizes)
+
+    def grad(self, g, order: np.ndarray, dst: np.ndarray,
+             n: int) -> np.ndarray:
+        """Host ``(n, D)`` gradients of the last ``lookup``'s unique rows
+        from the device gradient ``g`` ``(B, F, D)`` of its pooled rows.
+        ``order`` lists every slot (flat ``b * S + s``) once, sorted by
+        the unique row it read, and ``dst`` that row (ascending)."""
+        s = len(self.field_of)
+        order = np.asarray(order)
+        src = ((order // s) * len(self.sizes)
+               + self.field_of[order % s]).astype(np.int32)
+        dst = np.asarray(dst, np.int32)
+        count_h2d(src, dst)
+        full = _pooled_grad(g, src, dst, rows=self.cap)
+        parts = to_host(*(_get_rows(full, np.int32(lo))
+                          for lo in range(0, n, POOL_CHUNK)))
+        return np.concatenate(parts)[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

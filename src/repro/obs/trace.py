@@ -17,6 +17,9 @@ Design constraints, in order:
   into ``Record.meta``, which crosses the FileQueue for free (records
   are whole-pickled frames), letting the consumer reconstruct the
   queue-dwell span and parent the apply under it.
+* **Counters.** ``count(name, n)`` adds to a named total the tracer
+  keeps (``counters``) while it is enabled; with ``annotate`` each count
+  is also a zero-length annotation of that name carrying ``value=n``.
 * **On the profiler's clock when asked.** With ``annotate=True`` each
   ``begin``/``end`` pair also enters and exits a
   ``jax.profiler.TraceAnnotation`` under the span's bare name (no
@@ -98,6 +101,7 @@ class Tracer:
         self._n = 0  # spans ever recorded (ring wraps past capacity)
         self._ctx: list = []  # (trace, span) stack for implicit parenting
         self._open: dict = {}  # id -> _Span, begun but not yet ended
+        self.counters: dict = {}  # name -> total of count()
         # pid-salted id base: spans from different processes never
         # collide when their exports are merged supervisor-side
         self._base = (os.getpid() & 0xFFFF) << 32
@@ -198,6 +202,15 @@ class Tracer:
                   attrs or None)
         return sid
 
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the counter ``name`` (see the module docstring)."""
+        if not self.enabled:
+            return
+        self.counters[name] = self.counters.get(name, 0) + n
+        if self._annotation is not None:
+            with self._annotation(name, value=n):
+                pass
+
     def _put(self, name, trace, sid, parent, t0, t1, attrs) -> None:
         self._buf[self._n % self.capacity] = (
             name, trace, sid, parent, t0, t1, attrs)
@@ -238,6 +251,7 @@ class Tracer:
         self._n = 0
         self._ctx = []
         self._open = {}
+        self.counters = {}
 
 
 # -- module-global tracer ---------------------------------------------

@@ -110,10 +110,12 @@ class Momentum(Optimizer):
 @dataclass(frozen=True)
 class Adagrad(Optimizer):
     eps: float = 1e-8
+    initial_accumulator: float = 0.0
     name: str = "adagrad"
 
     def init_slots(self, param):
-        return {"n": jnp.zeros(param.shape, jnp.float32)}
+        return {"n": jnp.full(param.shape, self.initial_accumulator,
+                              jnp.float32)}
 
     def update(self, param, slots, grad, step):
         g = _f32(grad)

@@ -43,6 +43,12 @@ from repro.serving.scheduler import (AdmissionConfig, DEFAULT_BUCKETS,
                                      PredictScheduler)
 
 
+def needs_dense_features(cfg: CTRConfig) -> bool:
+    """Whether a model reads dense features, which the serve path does
+    not carry."""
+    return cfg.dense_features > 0
+
+
 class ServingPlane:
     """Serving-side subsystem over a cluster's slave replica sets."""
 
@@ -81,7 +87,14 @@ class ServingPlane:
                      name: Optional[str] = None) -> Scenario:
         """Register a serving scenario: validates its group subset against
         the shared store, builds its predict fn, cache namespace, and
-        micro-batching scheduler."""
+        micro-batching scheduler. A model that reads dense features
+        (``CTRConfig.dense_features``, DLRM-DCNv2) is refused: a predict
+        request carries ids only."""
+        if needs_dense_features(cfg):
+            raise ValueError(
+                f"serving model {cfg.name!r} ({cfg.model_type}) needs "
+                f"{cfg.dense_features} dense features an example, and the "
+                f"serve path carries feature ids only")
         groups = ctr_model.groups_for(cfg)
         ctr_model.check_scenario_groups(groups, self.store_groups)
         cache = ServeCache(groups, max_rows=self.cache_rows,

@@ -38,6 +38,22 @@ class RowRouter:
         return np.unique(np.asarray(ids, dtype=np.int64).reshape(-1),
                          return_inverse=True)
 
+    @staticmethod
+    def unique_order(ids: np.ndarray) -> tuple:
+        """``unique`` and the order that sorts the flattened ids: (unique
+        ids, inverse, order), ``inverse[order]`` ascending — what
+        ``np.unique`` computes on the way, kept for a caller that reduces
+        by unique id with sorted indices."""
+        flat = np.asarray(ids, dtype=np.int64).reshape(-1)
+        order = np.argsort(flat)
+        sid = flat[order]
+        first = np.empty(len(sid), bool)
+        first[:1] = True
+        np.not_equal(sid[1:], sid[:-1], out=first[1:])
+        inverse = np.empty(len(flat), np.intp)
+        inverse[order] = np.cumsum(first) - 1
+        return sid[first], inverse, order
+
     def pull(self, uniq: np.ndarray, groups: dict[str, int],
              owner: np.ndarray,
              fetch: Callable[[int, np.ndarray], dict[str, np.ndarray]],

@@ -82,7 +82,7 @@ class TrainPipeline:
         positives) land in the buffer as their feedback arrives;
         window-expiry emissions arrive at the next tick."""
         self.joiner.offer_exposures(batch.t, batch.view_ids,
-                                    batch.feature_ids)
+                                    batch.feature_ids, batch.dense)
         self._fb_t = np.concatenate([self._fb_t, batch.fb_t])
         self._fb_v = np.concatenate([self._fb_v, batch.fb_view_ids])
         self._deliver_feedback(batch.t)
@@ -151,17 +151,19 @@ class TrainPipeline:
         while self._buffered >= self.buckets[0] or \
                 (flush and self._buffered):
             with tr.span("train.drain"):
-                ids, y, w = self._take(min(self._buffered, top))
+                ids, y, w, dense = self._take(min(self._buffered, top))
+            kw = {} if dense is None else {"dense_x": dense}
             out.append(self.plane.train_batch(
                 self.scn, ids, y, weights=w, now=now,
-                bucket=self.bucket_for(len(ids))))
+                bucket=self.bucket_for(len(ids)), **kw))
         return out
 
     def flush(self, now: float) -> list[dict]:
         return self.tick(now, flush=True)
 
-    def _take(self, n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Pop the ``n`` oldest buffered samples as one train batch."""
+    def _take(self, n: int) -> tuple:
+        """Pop the ``n`` oldest buffered samples as one train batch: (ids,
+        labels, weights, dense features or None)."""
         take, got = [], 0
         while got < n and self._buf:
             b = self._buf[0]
@@ -176,7 +178,8 @@ class TrainPipeline:
                 got = n
         self._buffered -= got
         merged = JoinedBatch.concat(take)
-        return merged.feature_ids, merged.labels, merged.weights
+        return merged.feature_ids, merged.labels, merged.weights, \
+            merged.dense
 
     # ------------------------------------------------------------------
     # metrics

@@ -23,7 +23,16 @@ micro-batch scheduler, scenario registry), training was still one
             │   StreamingEvaluator (the downgrade trigger signal)
             └ push: per-row grads segment-summed over the batch inverse,
                 routed to owner masters; per-scenario dense head updated
-                through the shared optimizer and re-broadcast
+                through its optimizer (the store's, or the scenario's
+                ``dense_optimizer``) and re-broadcast
+
+A multi-hot model (DLRM-DCNv2) keeps the one dedup and the one pull of
+unique rows, then pools on the device (``kernels.ops.PooledLookup``):
+the unique rows and the inverse go up once, each field's slots are
+gathered and summed there, the tower runs on the pooled rows and the
+batch's dense features, and the gradient comes back already reduced
+to the unique rows — the ``(B, slots, dim)`` rows never cross the
+host-device boundary.
 
 Scenarios (``registry.py``) either share store groups or own namespaced
 ones created online on every shard — N models training concurrently off
@@ -44,9 +53,20 @@ from repro.core.routing import RoutingPlan
 from repro.kernels.device_io import count_h2d, to_host
 from repro.models import ctr as ctr_model
 from repro.obs import trace as obs_trace
-from repro.optim import Optimizer
+from repro.optim import Optimizer, get_optimizer
+from repro.kernels import ops
 from repro.serving.router import RowRouter
 from repro.training.registry import TrainRegistry, TrainScenario
+
+# Adagrad's starting sum for a tower that trains apart from the store
+# (TensorFlow's ``initial_accumulator_value``, as DLRM-DCNv2 trains)
+TOWER_ADAGRAD_INITIAL_SUM = 0.1
+
+def _padded(a, n: int) -> np.ndarray:
+    """Float32 ``a`` extended with zero rows to ``n`` rows."""
+    out = np.zeros((n,) + np.shape(a)[1:], np.float32)
+    out[:len(a)] = a
+    return out
 
 
 class TrainingPlane:
@@ -56,7 +76,7 @@ class TrainingPlane:
                  store_groups: dict[str, int], optimizer: Optimizer, *,
                  feature_filter: Optional[FeatureFilter] = None,
                  on_new_groups: Optional[Callable] = None,
-                 seed: int = 0):
+                 seed: int = 0, max_batch: int = 4096):
         self.plan = plan
         self.masters = masters
         self.store_groups = store_groups      # live view of the PS groups
@@ -66,6 +86,7 @@ class TrainingPlane:
         # when an isolated scenario adds namespaced groups
         self.on_new_groups = on_new_groups
         self.seed = seed
+        self.max_batch = max_batch            # sizes a multi-hot pool
         self.router = RowRouter(plan)
         self.registry = TrainRegistry()
 
@@ -107,16 +128,36 @@ class TrainingPlane:
 
         dense = ctr_model.init_dense(
             cfg, jax.random.PRNGKey(self.seed + len(self.registry)))
-        dense_slots = {k: self.optimizer.init_slots(jnp.asarray(v))
+        opt = self.dense_optimizer(cfg)
+        dense_slots = {k: opt.init_slots(jnp.asarray(v))
                        for k, v in dense.items()}
+        pool = None
+        if cfg.multi_hot:
+            (g, dim), = groups.items()
+            pool = ops.PooledLookup(cfg.multi_hot, dim,
+                                    self.max_batch * cfg.id_slots)
         scn = TrainScenario(
             name=name, cfg=cfg, group_map=group_map, groups=groups,
             predict=ctr_model.predict_fn(cfg),
             loss_grads=ctr_model.weighted_loss_and_grads_fn(cfg),
-            dense=dense, dense_slots=dense_slots, dense_prefix=dense_prefix)
+            dense=dense, dense_slots=dense_slots, dense_prefix=dense_prefix,
+            dense_step=jax.jit(opt.update_tree) if dense else None,
+            pool=pool)
         for dn, v in dense.items():
             self.masters[0].push_dense(scn.dense_store_name(dn), v)
         return self.registry.add(scn)
+
+    def dense_optimizer(self, cfg: CTRConfig) -> Optimizer:
+        """The optimizer of a scenario's dense tensors: the store's, unless
+        the model names its own (``dense_optimizer``, at ``lr``; Adagrad
+        starts its sums at ``TOWER_ADAGRAD_INITIAL_SUM``)."""
+        name = cfg.dense_optimizer
+        if not name or name == self.optimizer.name:
+            return self.optimizer
+        kw = {"lr": cfg.lr}
+        if name == "adagrad":
+            kw["initial_accumulator"] = TOWER_ADAGRAD_INITIAL_SUM
+        return get_optimizer(name, **kw)
 
     def scenario(self, name: Optional[str] = None) -> TrainScenario:
         return self.registry.get(name)
@@ -144,15 +185,133 @@ class TrainingPlane:
     def train_batch(self, scn: TrainScenario, ids: np.ndarray,
                     y: np.ndarray, *, now: float = 0.0,
                     weights: Optional[np.ndarray] = None,
-                    bucket: Optional[int] = None) -> dict:
+                    bucket: Optional[int] = None,
+                    dense_x: Optional[np.ndarray] = None) -> dict:
         """One online-learning step for one scenario: predict-before-train
         validation, weighted loss, gradient push through the PS
         optimizer. ``bucket`` pads rows/labels/weights up to that example
         count (padding weight 0) so the jitted fns compile once per
-        bucket shape."""
+        bucket shape. ``dense_x`` (B, dense_features) are the examples'
+        dense features, for a model that reads them."""
+        want = scn.cfg.dense_features
+        if want and (dense_x is None or np.shape(dense_x) !=
+                     (np.shape(ids)[0], want)):
+            raise ValueError(
+                f"model {scn.cfg.name!r} needs dense_x of shape "
+                f"({np.shape(ids)[0]}, {want}), got "
+                f"{None if dense_x is None else np.shape(dense_x)}")
         with obs_trace.get_tracer().span("train.batch"):
+            if scn.pool is not None:
+                return self._train_pooled(scn, ids, y, dense_x, now=now,
+                                          weights=weights, bucket=bucket)
             return self._train_batch(scn, ids, y, now=now, weights=weights,
                                      bucket=bucket)
+
+    def _admit(self, scn: TrainScenario, ids: np.ndarray,
+               uniq: np.ndarray) -> np.ndarray:
+        """The admitted ids of a batch's ``uniq``: ONE dedup serves
+        admission, pull, and push."""
+        scn.stats.raw_ids += ids.size
+        scn.stats.unique_ids += len(uniq)
+        return self.filter.admit(uniq) if self.filter is not None else uniq
+
+    def _pad_to(self, scn: TrainScenario, b: int,
+                bucket: Optional[int]) -> int:
+        """The padded example count of a ``b``-example batch."""
+        nb = b if bucket is None or bucket < b else bucket
+        if nb > b:
+            scn.stats.padded_examples += nb - b
+            scn.stats.bucket_counts[nb] = \
+                scn.stats.bucket_counts.get(nb, 0) + 1
+        return nb
+
+    def _split(self, uniq: np.ndarray, admitted: np.ndarray) -> dict:
+        """{master: its admitted ids of ``uniq``}: non-admitted ids are
+        dropped BEFORE the push, so they never create rows."""
+        keep = np.isin(uniq, admitted, assume_unique=True) \
+            if len(admitted) != len(uniq) else None
+        return self.plan.split_by_master(uniq if keep is None
+                                         else uniq[keep])
+
+    def _push_rows(self, scn: TrainScenario, group: str, uniq: np.ndarray,
+                   by_master: dict, agg: np.ndarray) -> None:
+        """Push the ``(U, dim)`` unique-row gradients of one group to their
+        owner masters (``by_master`` from ``_split``)."""
+        with obs_trace.get_tracer().span("train.grad_agg"):
+            parts = [(mid, mids, agg[np.searchsorted(uniq, mids)])
+                     for mid, mids in by_master.items()]
+        for mid, mids, gm in parts:
+            self.masters[mid].push_grad(scn.group_map[group], mids, gm,
+                                        step=scn.step)
+
+    def _update_dense(self, scn: TrainScenario, dense_j: dict,
+                      dense_grads: dict) -> None:
+        """The dense tensors' optimizer step (one jitted program over all
+        of them) and their push to master shard 0: a ``train.dense_update``
+        span."""
+        if not dense_grads:
+            return
+        with obs_trace.get_tracer().span("train.dense_update"):
+            new_w, scn.dense_slots = scn.dense_step(
+                dense_j, scn.dense_slots, dense_grads, scn.step)
+            names = list(new_w)
+            for dn, v in zip(names, to_host(*(new_w[k] for k in names))):
+                scn.dense[dn] = v
+                self.masters[0].push_dense(scn.dense_store_name(dn), v)
+
+    def _train_pooled(self, scn: TrainScenario, ids: np.ndarray,
+                      y: np.ndarray, dense_x: np.ndarray, *, now: float,
+                      weights: Optional[np.ndarray],
+                      bucket: Optional[int]) -> dict:
+        """``train_batch`` of a multi-hot model: one dedup and one pull of
+        unique rows on the host, pooling and its transpose on the device
+        (``train.pool``), the tower on the pooled rows and ``dense_x``,
+        the unique-row gradients pushed, the tower updated
+        (``train.dense_update``)."""
+        tr = obs_trace.get_tracer()
+        ids = np.asarray(ids, dtype=np.int64)
+        b, s = ids.shape
+        y = np.asarray(y, np.float32)
+        w = np.ones(b, np.float32) if weights is None else \
+            np.asarray(weights, np.float32)
+        (group, _), = scn.groups.items()
+        with tr.span("train.dedup"):
+            uniq, inverse, order = RowRouter.unique_order(ids)
+            admitted = self._admit(scn, ids, uniq)
+        with tr.span("train.pull"):
+            rows = self.pull_unique(scn, uniq)[group]
+            nb = self._pad_to(scn, b, bucket)
+        with tr.span("train.pool"):
+            inv = np.zeros((nb, s), np.int32)
+            inv[:b] = inverse.reshape(b, s)
+            pooled = scn.pool.lookup(rows, inv)
+            tr.count("train.pooled_ids", b * s)
+        with tr.span("train.forward"):
+            x_in, y_in, w_in = (_padded(a, nb) for a in (dense_x, y, w))
+            count_h2d(x_in, y_in, w_in, *scn.dense.values())
+            x_j = jnp.asarray(x_in)
+            dense_j = {k: jnp.asarray(v) for k, v in scn.dense.items()}
+            # progressive validation (predict BEFORE applying the update)
+            p = to_host(scn.predict(pooled, dense_j, x_j))[0][:b]
+            point = scn.validator.observe(now, scn.step, y, p)
+            scn.evaluator.observe(now, scn.step, y, p, weights=w)
+            loss, g_pooled, dense_grads = scn.loss_grads(
+                pooled, dense_j, x_j, jnp.asarray(y_in), jnp.asarray(w_in))
+        with tr.span("train.grad_agg"):
+            # padding slots (zero gradient) go last, onto the last row
+            pad = np.arange(b * s, nb * s)
+            agg = scn.pool.grad(
+                g_pooled, np.concatenate([order, pad]),
+                np.concatenate([inverse[order],
+                                np.full(len(pad), len(uniq) - 1)]),
+                len(uniq))
+            by_master = self._split(uniq, admitted)
+        self._push_rows(scn, group, uniq, by_master, agg)
+        self._update_dense(scn, dense_j, dense_grads)
+        scn.step += 1
+        scn.stats.batches += 1
+        scn.stats.examples += b
+        return {"loss": float(to_host(loss)[0]), **point.values}
 
     def _train_batch(self, scn: TrainScenario, ids: np.ndarray,
                      y: np.ndarray, *, now: float,
@@ -168,18 +327,14 @@ class TrainingPlane:
         w = np.ones(b, np.float32) if weights is None else \
             np.asarray(weights, np.float32)
 
-        # ONE dedup serves admission, pull, and push
         with tr.span("train.dedup"):
             uniq, inverse = RowRouter.unique(ids)
-            scn.stats.raw_ids += ids.size
-            scn.stats.unique_ids += len(uniq)
-            admitted = self.filter.admit(uniq) if self.filter is not None \
-                else uniq
+            admitted = self._admit(scn, ids, uniq)
 
         with tr.span("train.pull"):
             vals = self.pull_unique(scn, uniq)
             rows = RowRouter.expand(vals, inverse, (b, f))
-            nb = b if bucket is None or bucket < b else bucket
+            nb = self._pad_to(scn, b, bucket)
             if nb > b:
                 pad = nb - b
                 rows = {g: np.concatenate(
@@ -187,9 +342,6 @@ class TrainingPlane:
                     in rows.items()}
                 y_in = np.concatenate([y, np.zeros(pad, np.float32)])
                 w_in = np.concatenate([w, np.zeros(pad, np.float32)])
-                scn.stats.padded_examples += pad
-                scn.stats.bucket_counts[nb] = \
-                    scn.stats.bucket_counts.get(nb, 0) + 1
             else:
                 y_in, w_in = y, w
 
@@ -206,37 +358,17 @@ class TrainingPlane:
                 rows_j, dense_j, jnp.asarray(y_in), jnp.asarray(w_in))
 
         # aggregate per-row grads over duplicate ids, push to owner
-        # masters; non-admitted ids are dropped BEFORE the push, so they
-        # never create rows (padding rows carry weight 0 → zero grads,
-        # and the [:b] slice drops them from the aggregation entirely)
+        # masters (padding rows carry weight 0 → zero grads, and the [:b]
+        # slice drops them from the aggregation entirely)
         with tr.span("train.grad_agg"):
-            if self.filter is not None and len(admitted) != len(uniq):
-                keep = np.isin(uniq, admitted, assume_unique=True)
-            else:
-                keep = None
-            by_master = self.plan.split_by_master(
-                uniq if keep is None else uniq[keep])
+            by_master = self._split(uniq, admitted)
         for group, g in row_grads.items():
             with tr.span("train.grad_agg"):
                 g = to_host(g)[0][:b].reshape(-1, g.shape[-1])  # (B*F, dim)
                 agg = np.zeros((len(uniq), g.shape[-1]), np.float32)
                 np.add.at(agg, inverse, g)
-                parts = [(mid, mids, agg[np.searchsorted(uniq, mids)])
-                         for mid, mids in by_master.items()]
-            store_g = scn.group_map[group]
-            for mid, mids, gm in parts:
-                self.masters[mid].push_grad(store_g, mids, gm, step=scn.step)
-        # dense updates (DNN head) on master shard 0
-        if dense_grads:
-            for dn, g in dense_grads.items():
-                count_h2d(scn.dense[dn])
-                new_w, new_slots = self.optimizer.update(
-                    jnp.asarray(scn.dense[dn]), scn.dense_slots[dn],
-                    g, scn.step)
-                scn.dense[dn] = to_host(new_w)[0]
-                scn.dense_slots[dn] = new_slots
-                self.masters[0].push_dense(scn.dense_store_name(dn),
-                                           scn.dense[dn])
+            self._push_rows(scn, group, uniq, by_master, agg)
+        self._update_dense(scn, dense_j, dense_grads)
 
         scn.step += 1
         scn.stats.batches += 1

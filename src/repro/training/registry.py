@@ -64,6 +64,8 @@ class TrainScenario:
     dense: dict[str, np.ndarray]              # model-named dense tensors
     dense_slots: dict[str, dict]
     dense_prefix: str = ""                    # store-name prefix for dense
+    dense_step: Optional[Callable] = None     # jitted tower update
+    pool: Optional[object] = None             # ops.PooledLookup (multi-hot)
     validator: ProgressiveValidator = field(
         default_factory=ProgressiveValidator)
     evaluator: StreamingEvaluator = field(default_factory=StreamingEvaluator)

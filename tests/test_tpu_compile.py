@@ -191,3 +191,51 @@ def test_kernels_keep_their_names(one_chip, mosaic):
     sc = _spec(one_chip, (N_IDS, 1), jnp.float32)
     assert _kernel_names(ops._dequantize_program.lower(q, sc).compile()) \
         == {"dequantize_rows"}
+
+
+# DLRM-DCNv2 at its published widths: one 2048-example batch of 214
+# multi-hot slots over 26 fields, 128-wide rows, the tower by its rows
+DLRM_B = 2048
+
+
+def test_pooled_lookup_and_transpose_compile(one_chip):
+    """The pooled lookup over the chunked unique-row buffer and its
+    transpose (a sorted segment sum): XLA ops, no kernel. Their large
+    temporaries are a batch's gathered rows (the lookup keeps two, the
+    transpose one), never the buffer's size on top."""
+    from repro.configs.weips_ctr import DLRM_DCNV2
+    slots = DLRM_DCNV2.id_slots
+    cap = ops.POOL_CHUNK * -(-DLRM_B * slots // ops.POOL_CHUNK)
+    rows = _spec(one_chip, (cap, 128), jnp.float32)
+    inv = _spec(one_chip, (DLRM_B, slots), jnp.int32)
+    c = ops._pooled_lookup.lower(rows, inv,
+                                 sizes=DLRM_DCNV2.multi_hot).compile()
+    gathered = DLRM_B * slots * 128 * 4
+    _check(c, kernel=False, below=2 * gathered + (8 << 20))
+    g = _spec(one_chip, (DLRM_B, DLRM_DCNV2.fields, 128), jnp.float32)
+    idx = _spec(one_chip, (DLRM_B * slots,), jnp.int32)
+    c = ops._pooled_grad.lower(g, idx, idx, rows=cap).compile()
+    _check(c, kernel=False, below=gathered + (1 << 20))
+
+
+@pytest.mark.parametrize("rows,d", [(512, 3456), (4096, 512), (8, 3456),
+                                    (4096, 1024), (256, 1)])
+def test_tower_rows_quantize_compiles(one_chip, mosaic, rows, d):
+    """The int8 codec over the DLRM tower's rows as the pusher pads them:
+    the cross layers' (rank, 3456) and (3456, rank) factors, a bias row,
+    the top MLP's first and last weights."""
+    x = _spec(one_chip, (rows, d), jnp.float32)
+    _check(ops._quantize_program.lower(x).compile(), kernel=True,
+           below=rows * d * 4 + (1 << 20))
+
+
+def test_fused_ftrl_apply_compiles_128_wide(one_chip, mosaic):
+    """The training hot path at DLRM's row width, on a master's arenas of
+    2^20 rows (2^22 key slots): updated in place."""
+    klo, khi, slot_of, shift = _keys(one_chip, HBM_CAP)
+    arena = _spec(one_chip, (1 << 20, 128), jnp.float32)
+    grads = _spec(one_chip, (N_IDS, 128), jnp.float32)
+    c = ops._ftrl_program.lower(
+        klo, khi, slot_of, arena, arena, arena, *_ids(one_chip), grads,
+        shift=shift, alpha=0.05, beta=1.0, l1=1.0, l2=1.0).compile()
+    _check(c, kernel=True, below=(1 << 20) * 128 * 4)
