@@ -25,10 +25,12 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.core.hashmap import EMPTY as _NO_ID
 from repro.core.hashmap import IdHashMap
+from repro.kernels.device_io import to_host
 from repro.obs import trace as obs_trace
 from repro.optim import Optimizer
 from repro.optim.optimizers import FTRL
@@ -570,13 +572,16 @@ class SparseTable:
 
 @dataclass
 class DenseBank:
-    """Named dense tensors (DNN hidden layers etc.) with version counters."""
+    """Named dense tensors (DNN hidden layers etc.) with version counters.
+    A tensor may be a device array (a tower the training plane updates on
+    the device); snapshots hold host numpy copies of every tensor."""
 
-    tensors: dict[str, np.ndarray] = field(default_factory=dict)
-    slots: dict[str, dict[str, np.ndarray]] = field(default_factory=dict)
+    tensors: dict[str, np.ndarray | jax.Array] = field(default_factory=dict)
+    slots: dict[str, dict[str, np.ndarray | jax.Array]] = field(
+        default_factory=dict)
     versions: dict[str, int] = field(default_factory=dict)
 
-    def put(self, name: str, value: np.ndarray,
+    def put(self, name: str, value: np.ndarray | jax.Array,
             slots: Optional[dict] = None) -> None:
         self.tensors[name] = value
         if slots is not None:
@@ -584,21 +589,29 @@ class DenseBank:
         self.versions[name] = self.versions.get(name, 0) + 1
 
     def snapshot(self) -> dict:
-        return {
-            "tensors": {k: v.copy() for k, v in self.tensors.items()},
-            "slots": {k: {n: a.copy() for n, a in s.items()}
-                      for k, s in self.slots.items()},
-            "versions": dict(self.versions),
-        }
+        return self._host_copy(list(self.tensors))
 
     def snapshot_delta(self, since: dict[str, int]) -> dict:
         """Same format as ``snapshot`` but holding only tensors whose
         version counter moved past ``since[name]``."""
-        names = [k for k, v in self.versions.items()
-                 if v > since.get(k, -1)]
+        return self._host_copy([k for k, v in self.versions.items()
+                                if v > since.get(k, -1)])
+
+    def _host_copy(self, names: list) -> dict:
+        """Host numpy copies of the named tensors and their slots: host
+        arrays copied, device arrays read back together in one blocking
+        read."""
+        flat = {(k, None): self.tensors[k] for k in names}
+        flat.update({(k, n): a for k in names
+                     for n, a in self.slots.get(k, {}).items()})
+        dev = [key for key, a in flat.items() if isinstance(a, jax.Array)]
+        read = dict(zip(dev, to_host(*(flat[key] for key in dev)))
+                    if dev else ())
+        host = {key: read[key] if key in read else a.copy()
+                for key, a in flat.items()}
         return {
-            "tensors": {k: self.tensors[k].copy() for k in names},
-            "slots": {k: {n: a.copy() for n, a in self.slots[k].items()}
+            "tensors": {k: host[k, None] for k in names},
+            "slots": {k: {n: host[k, n] for n in self.slots[k]}
                       for k in names if k in self.slots},
             "versions": {k: self.versions[k] for k in names},
         }
@@ -714,7 +727,7 @@ class MasterShard:
         """Apply gradient rows through the optimizer; record dirty IDs."""
         self.apply_batch(group, ids, grads, step=step)
 
-    def push_dense(self, name: str, value: np.ndarray,
+    def push_dense(self, name: str, value: np.ndarray | jax.Array,
                    slots: Optional[dict] = None) -> None:
         assert self.alive
         self.dense.put(name, value, slots)

@@ -196,9 +196,11 @@ class Pusher:
                            "t_push": sp.t0}
         n_rec = 0
         try:
+            dense = self._encode_dense([g for g, _ in gathered
+                                        if g.startswith("dense/")])
             for (group, op), ids in gathered.items():
                 if group.startswith("dense/"):
-                    n_rec += self._push_dense(group, op, now)
+                    n_rec += self._push_dense(group, dense.get(group), now)
                 else:
                     n_rec += self._push_sparse(group, op, ids, now)
         finally:
@@ -208,20 +210,28 @@ class Pusher:
         self.pushed_records += n_rec
         return n_rec
 
-    def _push_dense(self, group: str, op: str, now: float) -> int:
-        name = group[len("dense/"):]
-        value = self.shard.dense.tensors.get(name)
-        if value is None:
-            return 0
-        ver = self.shard.dense.versions[name]
-        # each row its own int8 scale: a matrix by its rows, a vector as
-        # one row (the replica restores the shape from ``meta``)
-        rows = value.reshape(-1, value.shape[-1]) if value.ndim > 1 \
-            else value.reshape(1, -1)
+    def _encode_dense(self, groups: list) -> dict:
+        """{group: (version, shape, payload)} of the flush's dense groups
+        whose tensor the bank holds, coded together: tensors that live on
+        the device are coded there and come back in one read."""
+        bank = self.shard.dense
+        have = [(g, g[len("dense/"):]) for g in groups]
+        have = [(g, n) for g, n in have if n in bank.tensors]
+        if not have:
+            return {}
+        values = [bank.tensors[n] for _, n in have]
         with obs_trace.get_tracer().span("sync.encode"):
-            payload = self.transform.encode_values(rows)
-        meta = {"codec": self.transform.name, "t": now,
-                "shape": value.shape}
+            payloads = self.transform.encode_tensors(values)
+        return {g: (bank.versions[n], tuple(v.shape), p)
+                for (g, n), v, p in zip(have, values, payloads)}
+
+    def _push_dense(self, group: str, coded: Optional[tuple],
+                    now: float) -> int:
+        if coded is None:
+            return 0
+        ver, shape, payload = coded
+        # the replica restores the tensor's shape from ``meta``
+        meta = {"codec": self.transform.name, "t": now, "shape": shape}
         if self._tmeta is not None:
             meta.update(self._tmeta)
         rec = Record(group=group, op="upsert",

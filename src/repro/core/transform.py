@@ -18,7 +18,9 @@ Codecs:
 Backends — mirroring the PS row engine's ``numpy|pallas`` switch:
   * ``numpy``   — CPU reference codecs (the fast path on CPU-only hosts);
   * ``pallas``  — the int8 encode routes through the ``delta_codec``
-    Pallas kernel (``kernels.ops.quantize_rows``): interpret mode off-TPU
+    Pallas kernel (``kernels.ops.quantize_rows``, and
+    ``kernels.ops.quantize_tensors`` for dense tensors, on the device or
+    the host, at their own shapes): interpret mode off-TPU
     (bit-matching the reference), Mosaic-compiled on TPU. Codecs without
     a kernel (identity, cast16) keep running the numpy engine end-to-end
     (``kernel_backed`` gates the routing) — never an error, and never a
@@ -42,8 +44,11 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import numpy as np
 
+from repro.kernels.delta_codec import tensor_rows
+from repro.kernels.device_io import to_host
 from repro.obs import trace as obs_trace
 from repro.optim import FTRL, Optimizer
 
@@ -144,11 +149,23 @@ class Transform:
             lambda v: {"values": v.astype(np.float32, copy=False)})
 
     def encode_values(self, v: np.ndarray) -> dict:
-        """The codec alone over (rows, D) values that are already serve
-        weights — a dense tensor's rows: no serve derivation from slots.
-        The payload never aliases ``v`` (a queued payload must not alias
-        a live tensor)."""
+        """The codec alone over (rows, D) host values that are already
+        serve weights — a dense tensor's rows: no serve derivation from
+        slots. The payload never aliases ``v`` (a queued payload must not
+        alias a live tensor)."""
         return {"values": np.array(v, np.float32)}
+
+    def encode_tensors(self, tensors: list) -> list[dict]:
+        """``encode_values`` over dense tensors, each by its rows (a
+        matrix's rows, a vector as one row; the shape travels in the
+        record's meta). Tensors on the device come to the host in one
+        blocking read."""
+        dev = [i for i, t in enumerate(tensors) if isinstance(t, jax.Array)]
+        host = list(tensors)
+        for i, h in zip(dev, to_host(*(tensors[i] for i in dev))
+                        if dev else ()):
+            host[i] = h
+        return [self.encode_values(tensor_rows(t)) for t in host]
 
     @staticmethod
     def decode(payload: dict, backend: str = "numpy") -> np.ndarray:
@@ -202,13 +219,21 @@ class Int8Transform(Transform):
         return self._assemble(w, slots, self._quantize_np)
 
     def encode_values(self, v):
-        # a dense tensor has few rows: pad them to a small floor, not to
-        # a sparse flush's
-        if self._device_path and len(v):
-            from repro.kernels import ops
-            q, scale = ops.quantize_rows(v, min_rows=8)
-            return {"q": q, "scale": scale}
         return self._quantize_np(v)
+
+    def encode_tensors(self, tensors):
+        # on the device path the kernel codes every tensor at its own
+        # shape and all the codes come back in one read; a tensor that
+        # lives on the device is coded where it lives, so its f32 values
+        # never cross (the ``sync.dense_resident`` counter)
+        if not self._device_path:
+            return super().encode_tensors(tensors)
+        from repro.kernels import ops
+        resident = sum(isinstance(t, jax.Array) for t in tensors)
+        if resident:
+            obs_trace.get_tracer().count("sync.dense_resident", resident)
+        return [{"q": q, "scale": scale}
+                for q, scale in ops.quantize_tensors(tensors)]
 
     @staticmethod
     def decode(payload, backend: str = "numpy"):

@@ -19,6 +19,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+BLOCK_ROWS = 256        # rows a grid step codes, unless the caller gives
+
+
+def tensor_rows(x):
+    """A dense tensor as the codec's rows, each row its own scale: a
+    matrix by its rows, a vector as one row (host or device arrays)."""
+    return x.reshape(-1, x.shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
+
 
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)
@@ -33,7 +41,7 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     s_ref[...] = scale
 
 
-def quantize_rows(x: jax.Array, *, block_rows: int = 256,
+def quantize_rows(x: jax.Array, *, block_rows: int = BLOCK_ROWS,
                   interpret: bool = False):
     """x (B, D) -> (q int8 (B, D), scale f32 (B, 1))."""
     b, d = x.shape
@@ -57,7 +65,7 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
 
 
 def dequantize_rows(q: jax.Array, scale: jax.Array, *,
-                    block_rows: int = 256, interpret: bool = False):
+                    block_rows: int = BLOCK_ROWS, interpret: bool = False):
     """(q int8 (B, D), scale (B, 1)) -> x f32 (B, D)."""
     b, d = q.shape
     block_rows = min(block_rows, b)

@@ -32,8 +32,10 @@ DEVICE_IO = DeviceIO()
 
 
 def count_h2d(*arrays) -> None:
-    """Count host arrays about to be handed to a jitted program."""
-    DEVICE_IO.h2d_bytes += sum(a.nbytes for a in arrays)
+    """Count the host arrays among ``arrays`` about to be handed to a
+    jitted program; an array already on the device is no upload."""
+    DEVICE_IO.h2d_bytes += sum(a.nbytes for a in arrays
+                               if isinstance(a, np.ndarray))
 
 
 def to_host(*outs) -> tuple:

@@ -15,7 +15,9 @@ The PS entry points (``embedding_scatter``, ``fused_lookup``,
 ``fused_gather``, ``fused_ftrl_apply``, ``quantize_rows``,
 ``dequantize_rows``) take host arrays of any length, pad them to a few
 power-of-two lengths — every distinct length compiles its own program —
-and return unpadded results. ``PooledLookup`` (multi-hot fields
+and return unpadded results. ``quantize_tensors`` takes dense tensors,
+whose shapes are fixed, on the device or the host, and codes each at
+its own shape. ``PooledLookup`` (multi-hot fields
 sum-pooled from a batch's unique rows, and its transpose) moves rows in
 fixed-size chunks through one device buffer instead, so its programs
 compile once per batch shape whatever the unique-row count. What they
@@ -229,17 +231,48 @@ def _dequantize_program(q, scale):
     return _dc.dequantize_rows(q, scale, interpret=_interpret())
 
 
-def quantize_rows(x, *, min_rows: int = _hm.OFFSET_BLOCK):
+def quantize_rows(x):
     """Row-wise absmax int8 of an (N, D) f32 array of any length: host
-    (q (N, D) int8, scale (N, 1) f32). Rows are padded to a power of two,
-    at least ``min_rows`` (a dense tensor's few rows take a smaller
-    floor than a sparse flush's)."""
+    (q (N, D) int8, scale (N, 1) f32). Rows are padded to a power of
+    two."""
     x = np.asarray(x, np.float32)
-    nb = _bucket(len(x), min_rows)
+    nb = _bucket(len(x))
     xp = x if len(x) == nb else _pad(x, nb, 0)
     count_h2d(xp)
     q, scale = to_host(*_quantize_program(xp))
     return q[:len(x)], scale[:len(x)]
+
+
+@jax.jit
+def _quantize_tensor_program(x):
+    """``_quantize_program`` over a dense tensor by its rows
+    (``delta_codec.tensor_rows``): the rows are padded to whole kernel
+    blocks inside the program and sliced off again, so only the real
+    rows' codes come out."""
+    rows = _dc.tensor_rows(x)
+    n = len(rows)
+    # up to one block, a block of whole 8-row tiles; past it, full blocks
+    block = min(_dc.BLOCK_ROWS, -(-n // 8) * 8)
+    xp = jnp.pad(rows, ((0, -n % block), (0, 0)))
+    q, scale = _dc.quantize_rows(xp, block_rows=block,
+                                 interpret=_interpret())
+    return q[:n], scale[:n]
+
+
+def quantize_tensors(xs) -> list:
+    """Row-wise absmax int8 of dense tensors, each by its rows at its own
+    shape (one program a shape, so no padding to a power of two): host
+    ``(q (rows, D) int8, scale (rows, 1) f32)`` for each, all read back in
+    one blocking read. A tensor that lives on the device is coded there,
+    so its f32 values never cross; a host tensor goes up as it is."""
+    xs = [x if isinstance(x, jax.Array) else np.asarray(x, np.float32)
+          for x in xs]
+    count_h2d(*xs)
+    outs = [a for x in xs for a in _quantize_tensor_program(x)]
+    for a in outs:                  # every copy under way before the wait
+        a.copy_to_host_async()
+    host = to_host(*outs)
+    return list(zip(host[0::2], host[1::2]))
 
 
 def dequantize_rows(q, scale):

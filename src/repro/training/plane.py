@@ -32,7 +32,10 @@ the unique rows and the inverse go up once, each field's slots are
 gathered and summed there, the tower runs on the pooled rows and the
 batch's dense features, and the gradient comes back already reduced
 to the unique rows — the ``(B, slots, dim)`` rows never cross the
-host-device boundary.
+host-device boundary. The tower, too, stays on the device from step to
+step: master 0's bank holds the updated device arrays, and the pusher
+codes them there (``Int8Transform.encode_tensors``), so only their int8
+records cross.
 
 Scenarios (``registry.py``) either share store groups or own namespaced
 ones created online on every shard — N models training concurrently off
@@ -248,14 +251,14 @@ class TrainingPlane:
                       dense_grads: dict) -> None:
         """The dense tensors' optimizer step (one jitted program over all
         of them) and their push to master shard 0: a ``train.dense_update``
-        span."""
+        span. The new tensors stay on the device: the next forward reads
+        them there, and master 0's bank holds the same arrays."""
         if not dense_grads:
             return
         with obs_trace.get_tracer().span("train.dense_update"):
             new_w, scn.dense_slots = scn.dense_step(
                 dense_j, scn.dense_slots, dense_grads, scn.step)
-            names = list(new_w)
-            for dn, v in zip(names, to_host(*(new_w[k] for k in names))):
+            for dn, v in new_w.items():
                 scn.dense[dn] = v
                 self.masters[0].push_dense(scn.dense_store_name(dn), v)
 
@@ -288,6 +291,8 @@ class TrainingPlane:
             tr.count("train.pooled_ids", b * s)
         with tr.span("train.forward"):
             x_in, y_in, w_in = (_padded(a, nb) for a in (dense_x, y, w))
+            # the tower goes up only while it is host arrays (seeded or
+            # restored); after its first update it lives on the device
             count_h2d(x_in, y_in, w_in, *scn.dense.values())
             x_j = jnp.asarray(x_in)
             dense_j = {k: jnp.asarray(v) for k, v in scn.dense.items()}

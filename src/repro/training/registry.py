@@ -61,7 +61,9 @@ class TrainScenario:
     groups: dict[str, int]                    # model group -> row dim
     predict: Callable                         # jitted (rows, dense) -> (B,)
     loss_grads: Callable                      # jitted (rows, dense, y, w)
-    dense: dict[str, np.ndarray]              # model-named dense tensors
+    dense: dict                               # model-named dense tensors:
+                                              # host arrays until the first
+                                              # update, device arrays after
     dense_slots: dict[str, dict]
     dense_prefix: str = ""                    # store-name prefix for dense
     dense_step: Optional[Callable] = None     # jitted tower update

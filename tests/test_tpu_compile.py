@@ -229,6 +229,21 @@ def test_tower_rows_quantize_compiles(one_chip, mosaic, rows, d):
            below=rows * d * 4 + (1 << 20))
 
 
+@pytest.mark.parametrize("shape", [(13, 512), (3456, 512), (512, 3456),
+                                   (3456, 1024), (3456,), (256, 1), (1,)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_tower_tensor_quantize_compiles(one_chip, mosaic, shape):
+    """The int8 codec over a DLRM tower tensor where it lives, at its own
+    shape (rows padded to whole kernel blocks inside the program): the
+    13-row first weight, the 3,456-row cross and top weights, a bias, the
+    one-column last weight and the one-element last bias."""
+    x = _spec(one_chip, shape, jnp.float32)
+    c = ops._quantize_tensor_program.lower(x).compile()
+    assert _kernel_names(c) == {"quantize_rows"}
+    rows = shape[0] if len(shape) > 1 else 1
+    _check(c, kernel=True, below=(rows + 255) * shape[-1] * 4 + (1 << 20))
+
+
 def test_fused_ftrl_apply_compiles_128_wide(one_chip, mosaic):
     """The training hot path at DLRM's row width, on a master's arenas of
     2^20 rows (2^22 key slots): updated in place."""
