@@ -6,14 +6,8 @@ it contains a ``/`` or ends in a known file suffix. Tokens containing
 spaces, globs, or placeholders are ignored; a trailing ``:<line>`` is
 stripped. Bare filenames (no ``/``) may live anywhere in the tree.
 
-With ``--check-bench`` the check also runs in reverse for benchmark
-results: every ``BENCH_*.json`` in the repo root must be referenced by
-at least one of the given docs. A committed result no doc mentions is
-an orphan — it silently drifts from the documented performance story.
-
-Usage: python scripts/check_doc_refs.py [--check-bench] DOC.md [...]
-Exits 1 listing broken references / orphaned results, 0 when
-everything resolves.
+Usage: python scripts/check_doc_refs.py DOC.md [...]
+Exits 1 listing broken references, 0 when everything resolves.
 """
 
 from __future__ import annotations
@@ -47,37 +41,21 @@ def resolves(tok: str) -> bool:
     return False
 
 
-def orphaned_bench(referenced: set[str]) -> list[str]:
-    """Committed BENCH_*.json files no checked doc references."""
-    return sorted(p.name for p in ROOT.glob("BENCH_*.json")
-                  if p.name not in referenced)
-
-
 def main(argv: list[str]) -> int:
-    check_bench = "--check-bench" in argv
-    argv = [a for a in argv if a != "--check-bench"]
     if not argv:
         print(__doc__)
         return 2
     broken = []
-    referenced: set[str] = set()
     for doc in argv:
         text = Path(doc).read_text()
         for tok in sorted(set(iter_refs(text))):
-            referenced.add(tok.rsplit("/", 1)[-1])
             if not resolves(tok):
                 broken.append(f"{doc}: `{tok}` does not resolve")
-    if check_bench:
-        for name in orphaned_bench(referenced):
-            broken.append(
-                f"{name}: orphaned benchmark result — referenced by no "
-                f"checked doc")
     for line in broken:
         print(line)
     if not broken:
-        extra = " and no benchmark result is orphaned" if check_bench else ""
         print(f"ok: all intra-repo references in {len(argv)} doc(s) "
-              f"resolve{extra}")
+              f"resolve")
     return 1 if broken else 0
 
 

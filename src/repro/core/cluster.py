@@ -3,7 +3,7 @@ learning workload — trainer + master PS (training plane), predictor + slave
 PS replicas (serving plane), joined by the streaming sync pipeline, with
 cold/hot fault tolerance, progressive validation and domino downgrade.
 
-This is the end-to-end object the examples and benchmarks drive.
+This is the end-to-end object the examples and ``perfbench`` drive.
 """
 
 from __future__ import annotations
@@ -135,8 +135,7 @@ class WeiPSCluster:
         self.scatters: list[Scatter] = []
         for sid in range(c.num_slave):
             rs = ReplicaSet([SlaveShard(sid, self.groups,
-                                        backend=c.ps_backend,
-                                        codec_backend=c.codec_backend)
+                                        backend=c.ps_backend)
                              for _ in range(c.num_replicas)])
             for rid, shard in enumerate(rs.replicas):
                 sc = Scatter(shard, self.queue, self.plan)
@@ -454,7 +453,7 @@ class WeiPSCluster:
             now, self.training.scenario().evaluator)
 
     # ------------------------------------------------------------------
-    # chaos / recovery controls (fault-tolerance benchmarks)
+    # chaos / recovery controls (fault-tolerance tests and examples)
     # ------------------------------------------------------------------
     def kill_master(self, shard_id: int) -> None:
         self.masters[shard_id].kill()
@@ -492,8 +491,7 @@ class WeiPSCluster:
         peer (whose consumer offsets the new Scatter inherits)."""
         c = self.ccfg
         rs = self.replica_sets[shard_id]
-        shard = SlaveShard(shard_id, self.groups, backend=c.ps_backend,
-                           codec_backend=c.codec_backend)
+        shard = SlaveShard(shard_id, self.groups, backend=c.ps_backend)
         offsets = rs.add_replica(shard, bootstrap=self._bootstrap_replica)
         if offsets is None:
             # peer-copied state already reflects everything the peer's
@@ -551,7 +549,7 @@ class WeiPSCluster:
             (now - sc.last_record_time for sc in self.scatters
              if sc.shard.alive), default=0.0))
         # event→deployed staleness (push→scatter→cache-visible) across
-        # every live scatter consumer — the harness's headline SLO
+        # every live scatter consumer — the headline sync SLO
         reg.register("staleness", lambda: PercentileRing.merged_percentiles(
             [sc.staleness for sc in self.scatters if sc.shard.alive],
             (50, 99)))

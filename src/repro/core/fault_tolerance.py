@@ -94,9 +94,9 @@ def _pack_rows(a: np.ndarray, backend: str) -> dict:
     return Int8Transform._quantize_np(a)
 
 
-def _unpack_rows(p: dict, backend: str) -> np.ndarray:
+def _unpack_rows(p: dict) -> np.ndarray:
     from repro.core.transform import Int8Transform
-    return Int8Transform.decode(p, backend=backend)
+    return Int8Transform.decode(p)
 
 
 def _compress_table_snap(tsnap: dict, backend: str) -> dict:
@@ -108,15 +108,15 @@ def _compress_table_snap(tsnap: dict, backend: str) -> dict:
     return out
 
 
-def _table_rows(tsnap: dict, backend: str = "numpy") -> dict:
+def _table_rows(tsnap: dict) -> dict:
     """Raw columnar rows of a (possibly compressed) table snapshot."""
     rows = {k: tsnap[k] for k in _ROW_KEYS}
     rows["slots"] = tsnap["slots"]
     if "deleted" in tsnap:
         rows["deleted"] = tsnap["deleted"]
     if tsnap.get("codec") == "int8":
-        rows["w"] = _unpack_rows(tsnap["w"], backend)
-        rows["slots"] = {n: _unpack_rows(v, backend)
+        rows["w"] = _unpack_rows(tsnap["w"])
+        rows["slots"] = {n: _unpack_rows(v)
                         for n, v in tsnap["slots"].items()}
     return rows
 
@@ -209,8 +209,7 @@ def iter_owner_rows(rows: dict, owner: np.ndarray):
         yield dst, _take_rows(rows, idx)
 
 
-def fold_chain(links_shard_snaps, codec_backend: str = "numpy") \
-        -> dict[int, dict]:
+def fold_chain(links_shard_snaps) -> dict[int, dict]:
     """Fold a full+delta chain of per-shard snapshots (apply order: full
     first) into full-equivalent columnar state — deletes drop rows, delta
     rows override base rows, dense tensors merge by version counter.
@@ -224,7 +223,7 @@ def fold_chain(links_shard_snaps, codec_backend: str = "numpy") \
     snaps: dict[int, dict] = {}
     for link in links_shard_snaps:
         for sid, snap in link.items():
-            tables = {g: _table_rows(t, codec_backend)
+            tables = {g: _table_rows(t)
                       for g, t in snap["tables"].items()}
             cur = snaps.get(sid)
             if cur is None:
@@ -489,8 +488,7 @@ class ColdBackup:
         v = version if version is not None else self.store.latest()
         assert v is not None, "no checkpoint available"
         links = self.chain(v)
-        snaps = fold_chain((c.shard_snaps for c in links),
-                           self.codec_backend)
+        snaps = fold_chain(c.shard_snaps for c in links)
         tip = links[-1]
         return {"version": tip.version, "created_at": tip.created_at,
                 "queue_offsets": tip.queue_offsets,

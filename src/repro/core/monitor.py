@@ -21,9 +21,8 @@ Two evaluators:
   duck-types the trigger interface (``history`` + ``smoothed``), so
   ``SmoothedThresholdTrigger`` reads either evaluator unchanged.
 
-Latency/staleness machinery shared by the SLO harness
-(``benchmarks/e2e_slo.py``), the serving plane's admission controller,
-and the sync plane's staleness meter:
+Latency/staleness machinery shared by the serving plane's admission
+controller and the sync plane's staleness meter:
 
 * ``PercentileRing`` — a fixed-size ring of recent scalar observations
   (latencies, join delays, staleness seconds) answering windowed
@@ -34,10 +33,10 @@ and the sync plane's staleness meter:
   latency-SLO trigger with zero new code.
 * ``ManualClock`` — an injectable time source (callable, like
   ``time.perf_counter``) that only advances when told to. Threaded
-  through the predict scheduler's admission controller and the SLO
-  harness, it replays overload scenarios deterministically in tier-1
-  tests: queueing delay becomes exact simulated seconds instead of
-  machine-dependent wall time.
+  through the predict scheduler's admission controller, it replays
+  overload scenarios deterministically in tier-1 tests: queueing delay
+  becomes exact simulated seconds instead of machine-dependent wall
+  time.
 """
 
 from __future__ import annotations

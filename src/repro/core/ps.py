@@ -828,10 +828,9 @@ class SlaveShard:
     application of stream records (last-writer-wins by ``seq``)."""
 
     def __init__(self, shard_id: int, groups: dict[str, int],
-                 backend: str = "numpy", codec_backend: str = "numpy"):
+                 backend: str = "numpy"):
         self.shard_id = shard_id
         self.backend = backend
-        self.codec_backend = codec_backend   # decode engine (transform.py)
         self.tables = {g: SparseTable(dim, backend=backend)
                        for g, dim in groups.items()}
         self.dense: dict[str, np.ndarray] = {}
@@ -883,8 +882,7 @@ class SlaveShard:
             name = record.group[len("dense/"):]
             ver = int(record.ids[0])
             if self.dense_versions.get(name, -1) < ver:
-                self.dense[name] = decode_record(
-                    record, backend=self.codec_backend).reshape(
+                self.dense[name] = decode_record(record).reshape(
                     record.meta.get("shape", (1, -1)))
                 self.dense_versions[name] = ver
         elif record.op == "delete":
@@ -892,7 +890,7 @@ class SlaveShard:
             if self.on_apply is not None:
                 self.on_apply(record.group, record.ids, "delete")
         else:
-            values = decode_record(record, backend=self.codec_backend)
+            values = decode_record(record)
             self.tables[record.group].scatter(record.ids, values)
             if self.on_apply is not None:
                 self.on_apply(record.group, record.ids, "upsert")
@@ -940,7 +938,7 @@ class SlaveShard:
                 continue
             ids_l, val_l = rows.setdefault(rec.group, ([], []))
             ids_l.append(rec.ids)
-            val_l.append(decode_record(rec, backend=self.codec_backend))
+            val_l.append(decode_record(rec))
             self._applied_seq[key] = max(last, rec.seq)
             self.applied_records += 1
             applied.append(rec)

@@ -5,8 +5,7 @@
   Gatherer   — deduplicating aggregation window with the paper's three
                trigger modes: real-time, threshold-based, period-based.
                Dedup ratio is tracked (the paper observes ≥90 % repetition
-               of updates within 10 s — benchmarks/sync_path.py reproduces
-               this with Zipfian update streams).
+               of updates within 10 s).
   Pusher     — reads *current full values* for the gathered IDs (eventual
                consistency at ID granularity: never increments), applies the
                model transform (FTRL z,n→w, dtype cast, int8 quant),
@@ -20,8 +19,7 @@
 The push and scatter stages are fully batched (no per-partition/per-chunk
 Python): one gather + one encode per (group, op), vectorized argsort
 routing to partitions, and one ownership filter + one coalesced scatter
-per poll — see ``Pusher.push`` / ``Scatter.poll``. ``benchmarks/
-sync_path.py`` measures this against the pre-refactor per-partition loop.
+per poll — see ``Pusher.push`` / ``Scatter.poll``.
 """
 
 from __future__ import annotations
@@ -323,7 +321,7 @@ class Scatter:
         # meta["t"] at push time, the apply happens here, and the apply
         # runs SlaveShard.on_apply (serve-cache invalidation) inline — so
         # now - meta["t"] at this point IS push→scatter→cache-visible,
-        # the SLO the ROADMAP's harness measures. Deferred import keeps
+        # the staleness ``sync_metrics`` reports. Deferred import keeps
         # streaming.py free of a monitor dependency at module load.
         from repro.core.monitor import PercentileRing
         self.staleness = PercentileRing(1 << 12)

@@ -20,12 +20,11 @@ bandwidth/staleness trade the paper's full-value-per-ID consistency
 contract makes safe (skipped pushes are never *wrong*, only stale).
 
 Backends: ``SyncConfig.codec_backend="pallas"`` routes the int8 codec's
-quantize through the ``delta_codec`` kernel (``docs/KERNELS.md``) and
-dequantizes on the host — bit-identical to the numpy mirror, so producer
-and consumer may run different backends. The model states synced here
-are dense jax pytrees, not PS tables, so the sparse fused path
-(probe→gather→update→scatter, ``ClusterConfig.ps_backend``) does not
-apply; rows enter the queue already device-materialized.
+quantize through the ``delta_codec`` kernel (``docs/KERNELS.md``);
+replicas decode on the host whatever backend encoded a record. The model
+states synced here are dense jax pytrees, not PS tables, so the sparse
+fused path (probe→gather→update→scatter, ``ClusterConfig.ps_backend``)
+does not apply; rows enter the queue already device-materialized.
 """
 
 from __future__ import annotations
@@ -91,11 +90,10 @@ class ServeReplica:
     mesh with different shardings — model routing for the dense plane)."""
 
     def __init__(self, cfg: ModelConfig, params_like: PyTree,
-                 bootstrap: bool = True, codec_backend: str = "numpy"):
+                 bootstrap: bool = True):
         """``bootstrap`` performs the paper's full synchronization (replica
         attach = checkpoint copy); streaming covers deltas thereafter."""
         self.cfg = cfg
-        self.codec_backend = codec_backend
         leaves, self.treedef = jax.tree_util.tree_flatten_with_path(
             params_like)
         self.paths = [_path_str(p) for p, _ in leaves]
@@ -111,7 +109,7 @@ class ServeReplica:
         key = (rec.group, rec.producer)
         if rec.seq < self._applied_seq.get(key, -1):    # strictly older only
             return False
-        values = decode_record(rec, backend=self.codec_backend)
+        values = decode_record(rec)
         kind = rec.meta["kind"]
         path = rec.meta["path"]
         if kind == "dense":
@@ -147,7 +145,7 @@ class ServeReplica:
                 ids_l, val_l = rows_by_path.setdefault(
                     rec.meta["path"], ([], []))
                 ids_l.append(rec.ids)
-                val_l.append(decode_record(rec, backend=self.codec_backend))
+                val_l.append(decode_record(rec))
                 self._applied_seq[key] = rec.seq
                 self.applied += 1
                 applied += 1
@@ -230,8 +228,7 @@ class ModelSyncEngine:
         self._flushes = 0
         self.pushed_bytes = 0
         self.skipped_dense = 0
-        self.replicas = [ServeReplica(cfg, params,
-                                      codec_backend=s.codec_backend)
+        self.replicas = [ServeReplica(cfg, params)
                          for _ in range(s.num_slaves)]
         self.consumers = [
             Consumer(self.queue, range(s.num_partitions))

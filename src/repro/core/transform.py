@@ -27,17 +27,16 @@ Backends — mirroring the PS row engine's ``numpy|pallas`` switch:
     silent regression to eager-jnp — so cluster configs can flip one flag
     for the whole sync plane.
 
-Decode runs on the host for every backend: a record's payload arrives as
-host numpy and its rows go straight into host tables, so a device round
-trip would buy nothing. The int8 decode is one f32 cast and one f32
-multiply, bit-identical to ``kernels.ops.dequantize_rows``, which stays
-for callers that hold device arrays.
+Decode has no backend: a record's payload arrives as host numpy and its
+rows go straight into host tables, so a device round trip would buy
+nothing. The int8 decode is one f32 cast and one f32 multiply, the
+arithmetic of ``kernels.ref.dequantize_rows``.
 
 ``encode`` is backend-routed per *instance* (the pusher owns a configured
 ``Transform``); ``decode`` is resolved per *record* (the scatter looks the
-codec class up from record metadata), so producer and consumer need not
-share a backend — exactly the paper's heterogeneous training/serving
-cluster split.
+codec class up from record metadata), so the consumer needs nothing but
+the record — exactly the paper's heterogeneous training/serving cluster
+split.
 """
 
 from __future__ import annotations
@@ -168,7 +167,7 @@ class Transform:
         return [self.encode_values(tensor_rows(t)) for t in host]
 
     @staticmethod
-    def decode(payload: dict, backend: str = "numpy") -> np.ndarray:
+    def decode(payload: dict) -> np.ndarray:
         return payload["values"]
 
     def payload_bytes(self, payload: dict) -> int:
@@ -186,7 +185,7 @@ class Cast16Transform(Transform):
         return {"values16": v.astype(np.float16)}
 
     @staticmethod
-    def decode(payload, backend: str = "numpy"):
+    def decode(payload):
         return payload["values16"].astype(np.float32)
 
 
@@ -194,8 +193,7 @@ class Int8Transform(Transform):
     """Row-wise absmax int8: 4x bandwidth reduction on the push stage.
     ``backend="pallas"`` encodes with the ``kernels/delta_codec.py``
     kernel; ``numpy`` is its CPU mirror (bit-compatible by construction —
-    the kernel body is the same arithmetic). Decode is host numpy on
-    either backend, bit-identical to the kernel's dequantize."""
+    the kernel body is the same arithmetic). Decode is host numpy."""
 
     name = "int8"
     kernel_backed = True
@@ -236,7 +234,7 @@ class Int8Transform(Transform):
                 for q, scale in ops.quantize_tensors(tensors)]
 
     @staticmethod
-    def decode(payload, backend: str = "numpy"):
+    def decode(payload):
         return payload["q"].astype(np.float32) * payload["scale"]
 
 
@@ -256,11 +254,10 @@ def make_transform(codec: str, optimizer: Optional[Optimizer] = None,
     return cls(optimizer if needs_opt else None, backend=backend)
 
 
-def decode_record(record, backend: str = "numpy") -> np.ndarray:
+def decode_record(record) -> np.ndarray:
     """Consumer-side decode: codec resolved from ``record.meta["codec"]``
-    (defaulting to identity for pre-codec records), backend chosen by the
-    *consumer* — producer and consumer backends are independent. A
-    ``sync.decode`` span of ``repro.obs.trace``."""
+    (defaulting to identity for pre-codec records), whatever backend
+    encoded it. A ``sync.decode`` span of ``repro.obs.trace``."""
     codec = record.meta.get("codec", "identity")
     with obs_trace.get_tracer().span("sync.decode"):
-        return _TRANSFORMS[codec].decode(record.payload, backend=backend)
+        return _TRANSFORMS[codec].decode(record.payload)

@@ -3,18 +3,17 @@ stage). Exposure events (impressions, carrying feature IDs) wait in a time
 window for matching feedback events (clicks); on window expiry the joined
 labeled sample is emitted — positive if feedback arrived, negative
 otherwise. The window length is the paper's model-effect vs. timeliness
-trade-off, swept by ``benchmarks/train_path.py``.
+trade-off.
 
 The joiner is columnar and vectorized: exposures are offered as whole
 batches (ids + feature matrices), expiry entries live in flat arrays that
 one argsort sweep drains per ``drain_batch`` call, and the pending store
 is an ``IdHashMap`` (view_id → row) over columnar feature/label arrays —
 no per-event Python anywhere on the batch path. The seed per-event
-dict+heap joiner is kept verbatim in ``benchmarks/train_path.py`` (the
-baseline) and as the oracle of the sample-equivalence property suite
-(``tests/test_join_props.py``): batch offers must emit the same samples,
-labels, and (deadline, view_id) ordering as the per-event loop — stale
-re-offer expiry entries included.
+dict+heap joiner is kept verbatim as the oracle of the sample-equivalence
+property suite (``tests/test_join_props.py``): batch offers must emit the
+same samples, labels, and (deadline, view_id) ordering as the per-event
+loop — stale re-offer expiry entries included.
 
 Two knobs beyond the seed semantics, both off by default:
 

@@ -2,8 +2,9 @@
 stage of the WeiPS pusher (paper §4.1.3), 4x wire-bandwidth reduction.
 
 Quantize: one VMEM pass computes the per-row absmax scale and the int8
-payload; dequantize is the scatter-side inverse. Row blocks of
-(block_rows, D) keep the reduction in-register (D is last-dim/lane-major).
+payload. Row blocks of (block_rows, D) keep the reduction in-register (D
+is last-dim/lane-major). The inverse is a cast and a multiply that the
+consumer runs on the host (``Int8Transform.decode``).
 
 Two consumers share this kernel (both through ``kernels/ops.py``, with a
 bit-identical numpy mirror in ``core/transform.py``): the streaming sync
@@ -58,25 +59,3 @@ def quantize_rows(x: jax.Array, *, block_rows: int = BLOCK_ROWS,
         interpret=interpret,
         name="quantize_rows",
     )(x)
-
-
-def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
-
-
-def dequantize_rows(q: jax.Array, scale: jax.Array, *,
-                    block_rows: int = BLOCK_ROWS, interpret: bool = False):
-    """(q int8 (B, D), scale (B, 1)) -> x f32 (B, D)."""
-    b, d = q.shape
-    block_rows = min(block_rows, b)
-    grid = (pl.cdiv(b, block_rows),)
-    return pl.pallas_call(
-        _dequant_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        interpret=interpret,
-        name="dequantize_rows",
-    )(q, scale)

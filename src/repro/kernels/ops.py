@@ -12,15 +12,15 @@ row-major with D padded to 128 lanes — a whole-table relayout copy 128/D
 times the arena's size before any row moves.
 
 The PS entry points (``embedding_scatter``, ``fused_lookup``,
-``fused_gather``, ``fused_ftrl_apply``, ``quantize_rows``,
-``dequantize_rows``) take host arrays of any length, pad them to a few
-power-of-two lengths — every distinct length compiles its own program —
-and return unpadded results. ``quantize_tensors`` takes dense tensors,
-whose shapes are fixed, on the device or the host, and codes each at
-its own shape. ``PooledLookup`` (multi-hot fields
-sum-pooled from a batch's unique rows, and its transpose) moves rows in
-fixed-size chunks through one device buffer instead, so its programs
-compile once per batch shape whatever the unique-row count. What they
+``fused_gather``, ``fused_ftrl_apply``, ``quantize_rows``) take host
+arrays of any length, pad them to a few power-of-two lengths — every
+distinct length compiles its own program — and return unpadded results.
+``quantize_tensors`` takes dense tensors, whose shapes are fixed, on the
+device or the host, and codes each at its own shape. ``PooledLookup``
+(multi-hot fields sum-pooled from a batch's unique rows, and its
+transpose) moves rows in fixed-size chunks through one device buffer
+instead, so its programs compile once per batch shape whatever the
+unique-row count. What they
 hand to the device and read back is counted, and each blocking read is a
 span, through ``kernels/device_io.py``.
 """
@@ -226,11 +226,6 @@ def _quantize_program(x):
     return _dc.quantize_rows(x, interpret=_interpret())
 
 
-@jax.jit
-def _dequantize_program(q, scale):
-    return _dc.dequantize_rows(q, scale, interpret=_interpret())
-
-
 def quantize_rows(x):
     """Row-wise absmax int8 of an (N, D) f32 array of any length: host
     (q (N, D) int8, scale (N, 1) f32). Rows are padded to a power of
@@ -273,15 +268,6 @@ def quantize_tensors(xs) -> list:
         a.copy_to_host_async()
     host = to_host(*outs)
     return list(zip(host[0::2], host[1::2]))
-
-
-def dequantize_rows(q, scale):
-    """Inverse of ``quantize_rows``: host (N, D) f32."""
-    q = np.asarray(q)
-    nb = _bucket(len(q))
-    ins = (_pad(q, nb, 0), _pad(scale, nb, 1))
-    count_h2d(*ins)
-    return to_host(_dequantize_program(*ins))[0][:len(q)]
 
 
 POOL_CHUNK = 16384       # rows a PooledLookup upload or read moves at once

@@ -84,8 +84,8 @@ def quantize_rows(x: jax.Array):
 
 def dequantize_rows(q: jax.Array, scale: jax.Array) -> jax.Array:
     """Inverse of ``quantize_rows``: int8 codes (B, D) × per-row scale
-    (B, 1) -> float32 rows. Bit-identical to the kernel path (one cast,
-    one multiply — no fused-reciprocal divergence)."""
+    (B, 1) -> float32 rows. Bit-identical to ``Int8Transform.decode``
+    (one cast, one multiply — no fused-reciprocal divergence)."""
     return q.astype(jnp.float32) * scale
 
 

@@ -1,6 +1,6 @@
-"""Production meshes. Functions, not module-level constants — importing
-this module never touches jax device state (device count is locked at
-first backend init; the dry-run sets XLA_FLAGS before importing jax)."""
+"""Device and process meshes. Functions, not module-level constants —
+importing this module never touches jax device state (device count is
+locked at first backend init)."""
 
 from __future__ import annotations
 
@@ -8,14 +8,6 @@ from dataclasses import dataclass
 from typing import Optional
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    """TPU v5e: 16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod.
-    The ``pod`` axis joins batch/data sharding only (pure DP across pods)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -31,12 +23,6 @@ def _auto(axes: tuple) -> tuple:
     sharding (``DuplicateSpecError``); the models place activations with
     ``with_sharding_constraint`` and leave the rest to propagation."""
     return (jax.sharding.AxisType.Auto,) * len(axes)
-
-
-# TPU v5e hardware constants (per chip) for the roofline model.
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # bytes/s
-ICI_LINK_BW = 50e9                # bytes/s per link
 
 
 # ---------------------------------------------------------------------------

@@ -26,53 +26,11 @@ PyTree = Any
 DATA, MODEL, POD = "data", "model", "pod"
 
 
-from dataclasses import dataclass
-
-
-@dataclass(frozen=True)
-class ShardingOptions:
-    """Layout policy knobs, iterated by the §Perf hillclimb.
-
-    embed_mode:
-      * "fsdp" (baseline): embedding/lm-head P(model, data). The D axis is
-        sharded on ``data``, which makes the logits einsum contract a
-        sharded dimension — XLA all-reduces the full global logits tensor
-        (measured: 318 GB/step on qwen1.5-4b train_4k).
-      * "tp": P(model, None) — vocab-TP with replicated D. Logits compute
-        locally as (B/data, S, V/model) blocks; only softmax stats and
-        dx/dhead grads cross shards.
-
-    fsdp:
-      * True (baseline, training): weight D-axes sharded on ``data`` —
-        every matmul allgathers its weight shard, amortized over thousands
-        of tokens/device in training.
-      * False (serving plane): weight-stationary TP — no per-step weight
-        allgathers. This is the paper's heterogeneous master/slave layout
-        split applied to the dense plane: the slave does NOT mirror the
-        master's partitioning (measured: llama-90b decode_32k spends 28 ms
-        of ICI time/token re-gathering FSDP weight shards).
-    """
-
-    embed_mode: str = "fsdp"
-    fsdp: bool = True
-    # serve layout when fsdp=False — selected by memory fit (launch/dryrun):
-    #  * "tp":   weights sharded `model`-way only (16-way). Zero extra
-    #            collectives at decode; needs params/16 + cache <= HBM
-    #            (llama-90b w/ int8 cache: 14.4 GB — fits; measured
-    #            2.1 ms/token collective).
-    #  * "tp2d": feature axes over (model, data) = 256-way weights, D
-    #            never sharded. Fits anything (jamba-398B: 4.3 GB/dev) at
-    #            the cost of (B,1,·)-sized activation psums (12 ms/token).
-    serve_layout: str = "tp"
-
-
 class MeshInfo:
     """Axis sizes + derived batch sharding axes for a mesh."""
 
-    def __init__(self, mesh: jax.sharding.Mesh,
-                 opts: Optional[ShardingOptions] = None):
+    def __init__(self, mesh: jax.sharding.Mesh):
         self.mesh = mesh
-        self.opts = opts or ShardingOptions()
         self.axes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.data = self.axes.get(DATA, 1)
         self.model = self.axes.get(MODEL, 1)
@@ -95,28 +53,6 @@ def _attn_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
             q_ax = None
         if not m.div(g, MODEL):
             kv_ax = None
-
-    if not m.opts.fsdp and m.opts.serve_layout == "tp2d":
-        # serving (weight-stationary 2D TP): never shard the contraction
-        # dim D; spread heads on `model` and head_dim on `data` when they
-        # divide — weights stay resident, decode psums are (B,1,·)-sized.
-        def serve_proj(n_heads):
-            ax_h = MODEL if m.div(n_heads, MODEL) else None
-            ax_e = DATA if (ax_h and m.div(e, DATA)) else (
-                MODEL if (not ax_h and m.div(e, MODEL)) else None)
-            return P(None, ax_h, ax_e)
-
-        qp, kvp = serve_proj(h), serve_proj(g)
-        specs = {
-            "norm": P(None),
-            "wq": qp, "wk": kvp, "wv": kvp,
-            "wo": P(qp[1], qp[2], None),
-        }
-        if cfg.qkv_bias:
-            specs["bq"] = P(qp[1], qp[2])
-            specs["bk"] = P(kvp[1], kvp[2])
-            specs["bv"] = P(kvp[1], kvp[2])
-        return specs
 
     def proj(base_len, ax, d_axis_pos):
         spec = [None] * base_len
@@ -145,17 +81,6 @@ def _attn_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
 
 
 def _mlp_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
-    if not m.opts.fsdp and m.opts.serve_layout == "tp2d":
-        # serving: F over (model, data) = full 2D TP, D unsharded; the
-        # w_down psum is (B,1,D)-sized at decode.
-        f2d = cfg.d_ff % (m.data * m.model) == 0
-        ax = (MODEL, DATA) if f2d else MODEL
-        return {
-            "norm": P(None),
-            "w_gate": P(None, ax),
-            "w_up": P(None, ax),
-            "w_down": P(ax, None),
-        }
     return {
         "norm": P(None),
         "w_gate": P(DATA, MODEL),
@@ -165,18 +90,6 @@ def _mlp_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
 
 
 def _moe_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
-    if not m.opts.fsdp and m.opts.serve_layout == "tp2d":
-        # serving: experts on `model`, expert-ffn on `data`, D unsharded.
-        e_ax = MODEL if m.div(cfg.num_experts, MODEL) else None
-        f_ax = DATA if m.div(cfg.d_ff, DATA) else (
-            None if e_ax else MODEL)
-        return {
-            "norm": P(None),
-            "router": P(None, None),
-            "w_gate": P(e_ax, None, f_ax),
-            "w_up": P(e_ax, None, f_ax),
-            "w_down": P(e_ax, f_ax, None),
-        }
     if m.div(cfg.num_experts, MODEL):
         up, down = P(MODEL, DATA, None), P(MODEL, None, DATA)
     else:
@@ -191,24 +104,6 @@ def _moe_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
 
 
 def _mamba_specs(cfg: ModelConfig, m: MeshInfo) -> dict:
-    if not m.opts.fsdp and m.opts.serve_layout == "tp2d":
-        di2d = cfg.d_inner % (m.data * m.model) == 0
-        ax = (MODEL, DATA) if di2d else MODEL
-        return {
-            "norm": P(None),
-            "wz": P(None, ax),
-            "wx": P(None, ax),
-            "wB": P(None, None),
-            "wC": P(None, None),
-            "wdt": P(None, None),
-            "conv_w": P(None, None),
-            "conv_b": P(None),
-            "A_log": P(None),
-            "D": P(None),
-            "dt_bias": P(None),
-            "gnorm": P(ax),
-            "out_proj": P(ax, None),
-        }
     return {
         "norm": P(None),
         "wz": P(DATA, MODEL),
@@ -248,25 +143,9 @@ def _segment_specs(seg: Segment, cfg: ModelConfig, m: MeshInfo) -> dict:
     return out
 
 
-def _strip_axis(spec_tree: PyTree, axis: str) -> PyTree:
-    """Replace ``axis`` with None in every PartitionSpec of the tree."""
-    def fix(p: P) -> P:
-        return P(*[None if ax == axis else ax for ax in p])
-    return jax.tree.map(fix, spec_tree,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
 def param_pspecs(cfg: ModelConfig, m: MeshInfo) -> PyTree:
     """PartitionSpec tree mirroring ``init_params`` output."""
-    if not m.opts.fsdp:
-        # serving: vocab sharding only, D unsharded — no gather on the
-        # lookup/logit paths (2D = 256-way for the big-model layout).
-        embed = (P((MODEL, DATA), None) if m.opts.serve_layout == "tp2d"
-                 else P(MODEL, None))
-    elif m.opts.embed_mode == "fsdp":
-        embed = P(MODEL, DATA)
-    else:
-        embed = P(MODEL, None)
+    embed = P(MODEL, DATA)
     specs: dict = {
         "embed": embed,
         "final_norm": P(None),
@@ -280,9 +159,6 @@ def param_pspecs(cfg: ModelConfig, m: MeshInfo) -> PyTree:
                          for s in cfg.encoder_segments],
             "final_norm": P(None),
         }
-    if not m.opts.fsdp and m.opts.serve_layout == "tp":
-        # pure TP-16 serving: train layout minus the FSDP data axis
-        specs = _strip_axis(specs, DATA)
     return specs
 
 

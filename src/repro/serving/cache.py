@@ -192,9 +192,8 @@ class ServeCache:
 
     def window_stats(self) -> dict:
         """Counter deltas since the previous ``window_stats`` call, then
-        start a new window. Lifetime counters (``stats``) are untouched —
-        the SLO harness reads per-measurement-window hit rates while the
-        benchmark's end-of-run totals stay intact."""
+        start a new window. Lifetime counters (``stats``) are untouched,
+        so per-window hit rates and end-of-run totals can both be read."""
         cur = {"hits": self.hits, "misses": self.misses,
                "invalidated": self.invalidated, "trims": self.trims}
         out = {k: cur[k] - self._window_mark[k] for k in cur}
@@ -238,9 +237,9 @@ class DenseCache:
         self.invalidated += 1
 
     def stats(self) -> dict:
-        """Same shape family as ``ServeCache.stats`` so the harness can
-        surface sparse and dense cache health uniformly: a dense "miss"
-        is a refresh (version moved → re-fetch)."""
+        """Same shape family as ``ServeCache.stats`` so sparse and dense
+        cache health surface uniformly: a dense "miss" is a refresh
+        (version moved → re-fetch)."""
         n = self.hits + self.refreshes
         return {"rows": len(self._cached), "hits": self.hits,
                 "misses": self.refreshes,

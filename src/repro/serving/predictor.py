@@ -1,5 +1,5 @@
-"""Serving plane: prefill and decode step factories + a batched request
-driver used by the serving example and benchmarks.
+"""Serving plane: the decode step factory + a batched request driver used
+by the serving example.
 
 ``serve_step`` consumes *serve params* — the slave-side state produced by
 the WeiPS ModelSyncEngine — and a KV/SSM cache; it appends ONE token per
@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models import decode_step, forward, init_cache
+from repro.models import decode_step, init_cache
 
 PyTree = Any
 
@@ -32,17 +32,6 @@ def make_serve_step(cfg: ModelConfig, jit: bool = True) -> Callable:
     if jit:
         return jax.jit(serve_step, donate_argnums=(1,))
     return serve_step
-
-
-def make_prefill_step(cfg: ModelConfig, jit: bool = True) -> Callable:
-    def prefill_step(params: PyTree, batch: dict):
-        logits, _ = forward(params, cfg, batch["tokens"],
-                            enc_context=batch.get("enc_context"))
-        return logits
-
-    if jit:
-        return jax.jit(prefill_step)
-    return prefill_step
 
 
 @dataclass

@@ -39,9 +39,9 @@ every ticket with an arrival time from an injectable clock.
 Shed tickets resolve to ``None`` in ``flush``'s ticket-ordered result
 list; counters keep ``executed + shed == offered`` balanced per request
 AND per example. Per-request queueing+service latency is recorded into a
-shared :class:`~repro.core.monitor.PercentileRing`, so the SLO harness,
-the admission controller, and the domino-downgrade trigger all read one
-percentile implementation.
+shared :class:`~repro.core.monitor.PercentileRing`, so the admission
+controller and the domino-downgrade trigger read one percentile
+implementation.
 """
 
 from __future__ import annotations
@@ -211,8 +211,8 @@ class PredictScheduler:
         returns per-request results in ticket order (``None`` for shed
         tickets). With ``budget``, at most that many examples execute
         (but always at least one request) and the remainder stays
-        pending for the next flush — the queueing behavior the overload
-        harness measures."""
+        pending for the next flush — the queueing behavior the admission
+        tests measure."""
         now = self.clock()
         dl = self.admission.deadline
         out: list = []                 # result slot per drained ticket

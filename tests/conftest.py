@@ -1,7 +1,6 @@
 import os
 
-# Tests run on the single real CPU device — the 512-device override is ONLY
-# for the dry-run launcher (see src/repro/launch/dryrun.py).
+# Tests run on the single real CPU device.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
@@ -9,8 +8,6 @@ import pytest
 
 
 def pytest_addoption(parser):
-    parser.addoption("--runslow", action="store_true", default=False,
-                     help="run tests marked slow")
     parser.addoption("--chaos", action="store_true", default=False,
                      help="run multi-process chaos-harness tests")
     parser.addoption("--chaos-seed", action="store", type=int, default=7,
@@ -21,18 +18,14 @@ def pytest_addoption(parser):
 
 
 def pytest_collection_modifyitems(config, items):
-    """Auto-skip ``slow``/``chaos`` tests unless opted in (chaos tests
-    spawn a process per PS shard — minutes, not ms)."""
-    run_slow = config.getoption("--runslow") or bool(os.environ.get("RUN_SLOW"))
+    """Auto-skip ``chaos`` tests unless opted in (they spawn a process
+    per PS shard — minutes, not ms)."""
     run_chaos = config.getoption("--chaos") or bool(os.environ.get("RUN_CHAOS"))
-    skip_slow = pytest.mark.skip(reason="slow: pass --runslow or RUN_SLOW=1")
     skip_chaos = pytest.mark.skip(reason="chaos: pass --chaos or RUN_CHAOS=1")
     # match the actual @pytest.mark markers, not item.keywords — keywords
     # include every parent node's *name*, so the tests/chaos directory
     # itself would gate even unmarked (in-process, tier-1) tests in it
     for item in items:
-        if item.get_closest_marker("slow") and not run_slow:
-            item.add_marker(skip_slow)
         if item.get_closest_marker("chaos") and not run_chaos:
             item.add_marker(skip_chaos)
 

@@ -159,8 +159,8 @@ def test_int8_compressed_restore_within_quant_error():
 
 
 def test_delta_checkpoint_is_small_and_cheap():
-    """The acceptance shape of BENCH_checkpoint_path.json, in miniature:
-    at ~10% dirty rows a delta is >= 5x smaller than a full."""
+    """At ~10% dirty rows a delta checkpoint is >= 5x smaller than a
+    full one."""
     rng = np.random.default_rng(3)
     plan = RoutingPlan(2, 1, 1)
     shards = _shards(2)

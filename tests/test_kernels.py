@@ -12,6 +12,7 @@ try:                       # hypothesis is a dev extra; the container may
 except ModuleNotFoundError:
     given = settings = st = None
 
+from repro.core import Int8Transform
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(0)
@@ -41,7 +42,7 @@ def test_codec_sweep(b, d):
     qr, sr = ref.quantize_rows(x)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
-    got = ops.dequantize_rows(q, s)
+    got = Int8Transform.decode({"q": q, "scale": s})
     np.testing.assert_allclose(np.asarray(got), np.asarray(x),
                                atol=float(np.abs(x).max()) / 120)
 
@@ -59,7 +60,7 @@ def test_codec_roundtrip_error_property(scale):
     x = jnp.asarray(np.linspace(-abs(scale) - 1, abs(scale) + 1, 256,
                                 dtype=np.float32)).reshape(1, 256)
     q, s = ops.quantize_rows(x)
-    back = ops.dequantize_rows(q, s)
+    back = Int8Transform.decode({"q": q, "scale": s})
     step = float(np.abs(x).max()) / 127.0
     assert float(np.abs(np.asarray(back) - np.asarray(x)).max()) <= \
         step / 2 + 1e-5

@@ -1,6 +1,5 @@
 """Launch-layer machinery on the single local device: abstract specs,
-sharding trees, lowering train/serve steps through jit (the 512-device
-production meshes are exercised by launch/dryrun.py, not in unit tests)."""
+sharding trees, lowering train/serve steps through jit."""
 
 import dataclasses
 

@@ -1,8 +1,8 @@
-"""Deterministic overload + staleness tests for the SLO machinery (ISSUE
-8): admission control in the predict scheduler, the shared
-PercentileRing, event→deployed staleness through Scatter, per-window
-cache counters, and the closed-loop harness under a ManualClock — every
-latency and staleness figure here is exact simulated seconds."""
+"""Deterministic overload + staleness tests for the SLO machinery:
+admission control in the predict scheduler, the shared PercentileRing,
+event→deployed staleness through Scatter, and per-window cache counters,
+under a ManualClock — every latency and staleness figure here is exact
+simulated seconds."""
 
 import numpy as np
 import pytest
@@ -183,52 +183,6 @@ class TestAdmission:
         out = s.flush()
         assert len(out) == 50 and all(p is not None for p in out)
         assert s.adm.shed_requests == 0
-
-
-# ---------------------------------------------------------------------------
-# closed-loop overload (harness + ManualClock)
-# ---------------------------------------------------------------------------
-def _harness(max_pending, **kw):
-    from repro.launch.slo import SLOConfig, SLOHarness
-    clk = ManualClock()
-    cfg = SLOConfig(rows=1 << 10, fields=4, req_batch=16, budget=64,
-                    train_events=32, warmup_ticks=2, measure_ticks=6,
-                    max_pending=max_pending, num_master=1, num_slave=1,
-                    num_replicas=1, lr_head=False, feedback_delay=0.2,
-                    join_window=1.0, seed=3, **kw)
-    return SLOHarness(cfg, clock=clk, tick_dt=1.0), clk
-
-
-@pytest.mark.slow
-class TestClosedLoopOverload:
-    def test_p50_unaffected_at_half_load(self):
-        h, _ = _harness(max_pending=128)
-        pt = h.run_point(0.5)
-        # under-offered: every request executes in the tick it arrived
-        # (zero simulated queueing), nothing sheds
-        assert pt["latency_s"]["p50"] == pytest.approx(0.0)
-        assert pt["admission"]["shed_examples"] == 0
-        assert pt["admission"]["executed_examples"] \
-            == pt["admission"]["offered_examples"]
-
-    def test_p99_bounded_under_2x_overload(self):
-        h, _ = _harness(max_pending=128)
-        pt = h.run_point(2.0)
-        # depth bound = 2 ticks of budget -> a ticket waits at most ~2
-        # simulated ticks before executing or shedding; without the bound
-        # the oldest ticket would wait ~measure_ticks ticks
-        assert pt["admission"]["shed_examples"] > 0
-        assert pt["latency_s"]["p99"] <= 3.0
-        assert pt["pending_examples"] <= 128
-
-    def test_unbounded_queue_without_admission(self):
-        h, _ = _harness(max_pending=None)
-        pt = h.run_point(2.0)
-        assert pt["admission"]["shed_examples"] == 0
-        # 2x offered vs budget: queue grows ~budget/tick through warmup
-        # and measurement; latency tail tracks the backlog
-        assert pt["pending_examples"] >= 64 * 6
-        assert pt["latency_s"]["p99"] > 3.0
 
 
 # ---------------------------------------------------------------------------

@@ -156,9 +156,10 @@ def test_codec_roundtrip_through_queue(codec):
 
 @pytest.mark.parametrize("codec", CODECS)
 def test_pallas_numpy_backends_bit_compatible(codec):
-    """Decoded slave weights are bit-identical between the numpy codec
-    backend and the pallas delta-codec kernel path (interpret mode
-    off-TPU) through the full push→queue→scatter spine."""
+    """Decoded slave weights are bit-identical between the numpy
+    backends and the pallas ones (the delta-codec kernel encodes, in
+    interpret mode off-TPU, and the replica's tables are device-backed)
+    through the full push→queue→scatter spine."""
     decoded = {}
     for backend in ("numpy", "pallas"):
         plan = RoutingPlan(1, 2, 4)
@@ -167,7 +168,7 @@ def test_pallas_numpy_backends_bit_compatible(codec):
         master = MasterShard(0, {"w": 8}, opt)
         col = Collector()
         master.collector = col
-        slaves = [SlaveShard(i, {"w": 8}, codec_backend=backend)
+        slaves = [SlaveShard(i, {"w": 8}, backend=backend)
                   for i in range(2)]
         scatters = [Scatter(s, queue, plan) for s in slaves]
         pusher = Pusher(master, queue, plan,
@@ -189,9 +190,9 @@ def test_pallas_numpy_backends_bit_compatible(codec):
 
 
 def test_pallas_replica_poll_makes_no_device_read():
-    """A replica with ``codec_backend="pallas"`` decodes the int8 records
-    of a poll on the host: the poll passes no blocking device read, and
-    its rows equal a numpy-backend replica's after the same poll."""
+    """A replica on the pallas PS backend decodes the int8 records of a
+    poll on the host: the poll passes no blocking device read, and its
+    rows equal a numpy-backend replica's after the same poll."""
     from repro.kernels.device_io import DEVICE_IO
     groups = {"w": 1, "v": 8}
     plan = RoutingPlan(1, 1, 2)
@@ -211,7 +212,7 @@ def test_pallas_replica_poll_makes_no_device_read():
         gatherer = Gatherer("realtime")
         gatherer.offer(col.drain())
         pusher.push(gatherer.flush(step), now=float(step))
-    replicas = {b: SlaveShard(0, groups, backend="pallas", codec_backend=b)
+    replicas = {b: SlaveShard(0, groups, backend=b)
                 for b in ("pallas", "numpy")}
     scatters = {b: Scatter(s, queue, plan) for b, s in replicas.items()}
     waits = DEVICE_IO.waits
@@ -280,17 +281,22 @@ def test_cross_partition_seq_streams_independent():
     assert slaves[0].skipped_records == 0
 
 
-def test_pipeline_does_not_override_slave_codec_backend():
-    """Producer and consumer codec backends are independent: wiring a
-    numpy-transform pipeline must not clobber a slave's configured
-    decode backend."""
-    from repro.core.streaming import SyncPipeline
-    opt = get_optimizer("ftrl")
-    master = MasterShard(0, {"w": 4}, opt)
-    slave = SlaveShard(0, {"w": 4}, codec_backend="pallas")
-    SyncPipeline(master, [slave], PartitionedQueue(4), RoutingPlan(1, 1, 4),
-                 make_transform("int8", opt, backend="numpy"))
-    assert slave.codec_backend == "pallas"
+@pytest.mark.parametrize("method", ["apply", "apply_batch"])
+def test_record_without_codec_decodes_as_identity(method):
+    """A record whose meta names no codec is applied as identity: its
+    ``values`` land in the replica's table unchanged, on the singleton
+    and the batched path alike."""
+    slave = SlaveShard(0, {"w": 4})
+    ids = np.array([3, 9, 40], np.int64)
+    values = np.arange(12, dtype=np.float32).reshape(3, 4) - 5.5
+    rec = Record(group="w", op="upsert", ids=ids,
+                 payload={"values": values}, seq=0, producer=0, meta={})
+    if method == "apply":
+        assert slave.apply(rec)
+    else:
+        assert slave.apply_batch([rec]) == [rec]
+    np.testing.assert_array_equal(slave.lookup("w", ids), values)
+    assert slave.applied_records == 1
 
 
 def test_batched_scatter_upsert_then_delete_ordering():
